@@ -11,8 +11,7 @@ reduced.
 Run:  python benchmarks/llama70b_sync.py [--layers 8] [--dtype bfloat16]
 
 Measures the buffered path and the direct + registered-staging path
-(publish is copy-free; the pull moves each byte once). Results are
-recorded in BASELINE.md.
+(publish is copy-free; the pull moves each byte once).
 """
 
 import argparse

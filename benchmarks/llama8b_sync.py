@@ -10,7 +10,6 @@ trainer->consumer sync paths end to end:
 Run:  python benchmarks/llama8b_sync.py [--dtype bfloat16] [--scale 1.0]
 
 ``--scale`` shrinks the hidden sizes for quick runs (1.0 = real 8B shapes).
-Results are recorded in BASELINE.md.
 """
 
 import argparse

@@ -19,8 +19,6 @@ volume processes are the system under test, exactly like bench.py.
 
 Run:  python benchmarks/moe_sync.py [--layers 2] [--dtype bfloat16]
       [--scale 1.0]
-
-Results are recorded in BASELINE.md.
 """
 
 import argparse

@@ -1,8 +1,10 @@
 """Direct one-hop weight sync: the store carries only metadata handles; the
 consumer pulls straight from the trainer's staging buffers (SHM on the same
-host). This is the steady-state RL weight-sync fast path. Run:
+host). This is the steady-state RL weight-sync fast path. Needs 8 devices;
+without 8 chips, run on virtual CPU devices:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/direct_sync.py
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/direct_sync.py
 """
 
 import asyncio
@@ -15,9 +17,11 @@ import torchstore_tpu as ts
 
 async def main():
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchstore_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     await ts.initialize(store_name="direct_demo")
     try:

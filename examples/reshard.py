@@ -1,8 +1,10 @@
 """Resharding demo: put a jax.Array on one mesh layout, get it on another,
 with PUT/GET wall-time printed (equivalent of the reference's
-example/dtensor.py). Run:
+example/dtensor.py). Needs 8 devices; without 8 chips, run on virtual CPU
+devices:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/reshard.py
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/reshard.py
 """
 
 import asyncio
@@ -15,9 +17,11 @@ import torchstore_tpu as ts
 
 async def main():
     import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from torchstore_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     await ts.initialize(store_name="reshard")
     try:

@@ -6,9 +6,18 @@ learner trains fsdp-sharded on its mesh, generators pull tensor-parallel on
 theirs — the store reshards automatically. Publishing rides the versioned
 weight channel (WeightPublisher/WeightSubscriber): the learner publishes,
 generators BLOCK until a newer version commits (no version bookkeeping, no
-polling), and old versions are garbage-collected automatically. Run:
+polling), and old versions are garbage-collected automatically.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/torchstore_rl.py
+The learner and each generator are separate actor PROCESSES that all use
+jax, and a chip belongs to one process at a time: on real hardware this
+layout needs a chip (or slice) per actor process. On a one-chip machine run
+it on virtual CPU devices:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/torchstore_rl.py
+
+(``chip_smoke.py`` drives the same trainer -> store -> generator loop on one
+chip from one process.)
 """
 
 import asyncio
@@ -22,16 +31,18 @@ STORE = "rl_example"
 STEPS = 3
 
 
-def _cpu_jax():
+def _jax():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    from torchstore_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     return jax
 
 
 class Learner(Actor):
     def __init__(self):
-        jax = _cpu_jax()
+        jax = _jax()
         import jax.numpy as jnp
         import optax
 
@@ -65,7 +76,7 @@ class Learner(Actor):
 
 class Generator(Actor):
     def __init__(self):
-        jax = _cpu_jax()
+        jax = _jax()
         import jax.numpy as jnp
 
         from torchstore_tpu import parallel

@@ -18,7 +18,7 @@ Accepted file shapes (both live in this repo):
   ``parsed: null`` contributes nothing and is reported as such).
 
 Usage:
-    python scripts/bench_compare.py BENCH_r04.json BENCH_r05.json
+    python scripts/bench_compare.py BENCH_r01.json BENCH_r05.json
     python scripts/bench_compare.py BENCH_r0*.json --baseline median
     python scripts/bench_compare.py old.json new.json --json --scale 1.5
 

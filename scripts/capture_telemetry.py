@@ -7,8 +7,7 @@ traffic), the elastic plane's dry-run view (``ts.autoscale_plan()`` plus
 the live fleet size it solved against — a ``--watch`` run leaves a
 fleet-size time series), and the fleet's retained time-series history
 (``ts.history()``),
-and writes the merged flight record to /tmp/ts_flight_record.json
-(tpu_watch.sh moves both into its OUTDIR during a device capture). Safe to
+and writes the merged flight record to /tmp/ts_flight_record.json. Safe to
 run anywhere a store can boot.
 
 ``--watch N`` keeps the store up and re-captures N times at ``--interval``

@@ -281,8 +281,8 @@ async def test_bench_streamed_sync_section_tiny():
 
 @pytest.mark.anyio
 async def test_bench_cold_path_section_tiny():
-    """The cold-path section standalone (what ``bench.py --cold-path`` and
-    tpu_watch's device capture run) at KB scale: real prewarm against real
+    """The cold-path section standalone (what ``bench.py --cold-path``
+    runs) at KB scale: real prewarm against real
     fleets, segments actually provisioned, both ratios computed — so the
     cold section can never ship broken (the r5 lesson)."""
     sys.path.insert(0, REPO_ROOT)

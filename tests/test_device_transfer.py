@@ -11,10 +11,6 @@ from torchstore_tpu.transport import device_transfer as dt
 
 jax = pytest.importorskip("jax")
 
-pytestmark = pytest.mark.skipif(
-    not dt.is_available(), reason="jax.experimental.transfer not in this build"
-)
-
 
 def _mesh(n=8):
     devs = np.array(jax.devices()[:n], dtype=object)
@@ -92,7 +88,6 @@ async def test_direct_sync_rides_device_path(store):
     out = await ts.get_state_dict(
         "m", user_state_dict=target, direct=True, store_name=store
     )
-    assert dt.is_available()
     assert hasattr(out["w"], "sharding")  # device array, not host copy
     np.testing.assert_array_equal(np.asarray(out["w"]), np.arange(64.0))
     np.testing.assert_array_equal(np.asarray(out["b"]), np.ones(8))
@@ -302,9 +297,9 @@ async def test_concurrent_fallback_pulls_share_one_staging(store):
         for d in dests:
             real_pull_once = d._pull_once
 
-            async def counted(handles, sd, _real=real_pull_once):
+            async def counted(*args, _real=real_pull_once):
                 pull_once_calls["n"] += 1
-                return await _real(handles, sd)
+                return await _real(*args)
 
             d._pull_once = counted
         gen_before = source._read_gen_locked()
@@ -381,3 +376,28 @@ async def test_ici_disabled_falls_back(store, monkeypatch):
         "fb", user_state_dict=target, direct=True, store_name=store
     )
     np.testing.assert_array_equal(np.asarray(out["w"]), np.arange(32.0))
+
+
+async def test_unserved_platform_takes_host_staging(store, monkeypatch):
+    """Arrays on a platform the transfer engine does not serve (a TPU, on
+    this installation — ``SERVED_PLATFORMS``) must not select the device
+    rung: the direct put registers host staging buffers and the pull still
+    lands equal device arrays."""
+    monkeypatch.setattr(dt, "SERVED_PLATFORMS", frozenset())
+    mesh = _mesh()
+    sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("x"))
+    sd = {"w": jax.device_put(jax.numpy.arange(64.0), sh)}
+    assert not dt.serves(sd["w"])
+    await ts.put_state_dict("h", sd, direct=True, store_name=store)
+    published = await ts.get("h/rank_0", store_name=store)
+    assert "device" not in published and len(published["handles"]["w"]) == 8
+    out = await ts.get_state_dict(
+        "h",
+        user_state_dict={
+            "w": jax.ShapeDtypeStruct((64,), jax.numpy.float32, sharding=sh)
+        },
+        direct=True,
+        store_name=store,
+    )
+    assert out["w"].sharding == sh
+    np.testing.assert_array_equal(np.asarray(out["w"]), np.arange(64.0))

@@ -396,9 +396,16 @@ async def test_diagnosis_routes_through_coordinator_when_sharded():
     """``_raise_with_diagnosis`` fans the health check out through the
     COORDINATOR (never a shard): killing a volume under a sharded store
     still yields the controller-diagnosed error string, and the client's
-    dead-volume memory comes from the coordinator's verdict."""
+    dead-volume memory comes from the coordinator's verdict. (Retry
+    deadline 3 s, not the default 30: the get retries against the surviving
+    volume until then, and tier 1 should not wait it out.)"""
+    from torchstore_tpu.config import RetryPolicy, StoreConfig
+
     await ts.initialize(
-        num_storage_volumes=2, store_name="mpdx", controller_shards=2
+        num_storage_volumes=2,
+        store_name="mpdx",
+        controller_shards=2,
+        config=StoreConfig(retry=RetryPolicy(deadline_s=3.0)),
     )
     try:
         c = ts.client("mpdx")

@@ -61,38 +61,23 @@ class TestNative:
 
 
 class TestOps:
-    def test_device_cast(self):
-        jax = pytest.importorskip("jax")
-        import jax.numpy as jnp
-
-        x = jnp.arange(64.0, dtype=jnp.float32)
-        out = __import__("torchstore_tpu.ops", fromlist=["device_cast"]).device_cast(
-            x, "bfloat16"
-        )
-        assert out.dtype == jnp.bfloat16
-
-    def test_pallas_cast_tiled(self):
+    @pytest.mark.parametrize(
+        "shape, dtype",
+        [((64,), "bfloat16"), ((32, 128), "bfloat16"), ((100,), "float16")],
+        ids=["1d", "tile-aligned", "unaligned"],
+    )
+    def test_device_cast(self, shape, dtype):
         pytest.importorskip("jax")
         import jax.numpy as jnp
 
-        from torchstore_tpu.ops import pallas_cast
+        from torchstore_tpu.ops import device_cast
 
-        x = jnp.arange(8 * 128 * 4, dtype=jnp.float32).reshape(32, 128)
-        out = pallas_cast(x, jnp.bfloat16)
-        assert out.dtype == jnp.bfloat16 and out.shape == x.shape
+        x = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape)
+        out = device_cast(x, dtype)
+        assert out.dtype == jnp.dtype(dtype) and out.shape == x.shape
         np.testing.assert_allclose(
             np.asarray(out, dtype=np.float32), np.asarray(x), rtol=1e-2
         )
-
-    def test_pallas_cast_unaligned_falls_back(self):
-        pytest.importorskip("jax")
-        import jax.numpy as jnp
-
-        from torchstore_tpu.ops import pallas_cast
-
-        x = jnp.arange(100.0, dtype=jnp.float32)  # not 1024-divisible
-        out = pallas_cast(x, jnp.float16)
-        assert out.dtype == jnp.float16 and out.shape == x.shape
 
     def test_ici_reshard(self):
         jax = pytest.importorskip("jax")

@@ -95,11 +95,16 @@ async def test_get_survives_volume_death(store):
 
 async def test_unreplicated_key_on_dead_volume_still_fails():
     # replication=1 control: a volume death LOSES its keys; the error must
-    # surface rather than silently serving stale/empty data.
+    # surface rather than silently serving stale/empty data. The get retries
+    # against the surviving volume until the retry deadline: 3 s here, not
+    # the default 30, so tier 1 does not wait it out.
+    from torchstore_tpu.config import RetryPolicy, StoreConfig
+
     await ts.initialize(
         num_storage_volumes=2,
         strategy=LocalRankStrategy(replication=1),
         store_name="repl1",
+        config=StoreConfig(retry=RetryPolicy(deadline_s=3.0)),
     )
     try:
         await ts.put("only", np.ones(4), store_name="repl1")

@@ -317,9 +317,9 @@ class TestUlysses:
 
 
 class TestPallasFlash:
-    """Pallas flash kernel (interpret mode on CPU; compiles and runs on the
-    real v5e chip at ~120 TFLOP/s — see BASELINE.md for the jitted-XLA
-    comparison)."""
+    """Pallas flash kernel in interpret mode on CPU (its compile for the
+    v5e is tests/test_chip_compile.py; its rate on the chip is not
+    measured)."""
 
     @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
     def test_matches_dense(self, causal):

@@ -317,8 +317,6 @@ async def _device_sync_scenario(rank: int, world: int, result: dict) -> None:
 
     import torchstore_tpu as ts
 
-    from torchstore_tpu.transport import device_transfer as dt
-
     await ts.initialize_spmd(store_name="devsync")
     w = np.arange(128.0, dtype=np.float32).reshape(16, 8)
     devs = jax.devices()
@@ -341,20 +339,11 @@ async def _device_sync_scenario(rank: int, world: int, result: dict) -> None:
         await ts.barrier("pulled", store_name="devsync")
     else:
         await ts.barrier("published", store_name="devsync")
-        # Zero-host-staging holds only where the jax build ships the XLA
-        # transfer engine (jax.experimental.transfer). This image's jax
-        # (0.4.37) predates it, so device_transfer.is_available() is False
-        # in EVERY process and registration deterministically falls back to
-        # host staging (root cause of the standing tier-1 failure — not a
-        # flake). The merged multi-rank pull below is path-independent and
-        # stays asserted either way.
+        # Zero host staging: all-jax sources register on the device rung.
         for r in (0, 1):
             published = await ts.get(f"policy/rank_{r}", store_name="devsync")
-            if dt.is_available():
-                assert published["handles"] == {}, "host buffers on device path"
-                assert published["device"] is not None
-            else:
-                assert published["handles"], "no handles on fallback path"
+            assert published["handles"] == {}, "host buffers on device path"
+            assert published["device"] is not None
         mesh8 = jax.sharding.Mesh(
             np.array(devs, dtype=object).reshape(8), ("x",)
         )

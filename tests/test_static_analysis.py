@@ -1154,7 +1154,7 @@ def test_stage_discipline_live_tree_clean():
     """The live tree stays clean under the new rule (baseline stays
     empty): every client- and volume-side stage segment records under a
     STAGE_CATALOG name, so the dominant-stage attribution in
-    ``ts.slo_report()`` folds both sides into one taxonomy."""
+    ``ts.slo_report()`` folds both sides into one stage catalog."""
     root = str(pathlib.Path(__file__).resolve().parents[1])
     result = run_checks(root, rules=["stage-discipline"])
     assert _msgs(result.findings, "stage-discipline") == []

@@ -2,7 +2,7 @@
 
 The stage-attribution layer (observability/timeline.py) only answers
 "which stage ate the p99 budget" if client and volume sites record their
-wall-clock segments under the SAME taxonomy: a volume labeling its landing
+wall-clock segments under the SAME stage catalog: a volume labeling its landing
 bracket ``"landing_copy"`` while the client records ``"landing"`` splits
 one stage into two digests and the dominant-stage vote silently fragments.
 ``ts.slo_report()``, the loadgen scoreboard merge, and the fleet_scale

@@ -663,10 +663,12 @@ async def prewarm(
     ``get_state_dict(direct=True)`` / ``WeightSubscriber.acquire`` starts at
     the data movement.
 
-    ADVISORY by contract: prewarm never raises and never fails the
-    subsequent sync — stage failures are logged, counted in
+    ADVISORY by contract for every host stage: those never raise and never
+    fail the subsequent sync — stage failures are logged, counted in
     ``ts_prewarm_errors_total``, reported in the returned dict, and the
-    lazy path serves exactly as before. Returns the provisioning report
+    lazy path serves exactly as before. Only a transfer server that cannot
+    start raises: the device rung has no lazy path to fall back on.
+    Returns the provisioning report
     (``segments``, ``bytes``, ``dials``, ``granted_bytes``, ``errors``,
     ...)."""
     from torchstore_tpu import provision

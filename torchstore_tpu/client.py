@@ -604,7 +604,7 @@ class LocalClient:
         for value in items.values():
             if shd.is_jax_array(value):
                 for shard in value.addressable_shards:
-                    shd._start_d2h(shard.data)
+                    shard.data.copy_to_host_async()
         requests: list[Request] = []
         for key, value in items.items():
             requests.extend(self._value_to_requests(key, value))
@@ -1258,6 +1258,15 @@ class LocalClient:
         )
         # volume_id -> list of (request_index, sub_request)
         by_volume: dict[str, list[tuple[int, Request]]] = {}
+        if any(
+            vid not in self._volume_refs
+            for infos in located.values()
+            for vid in infos
+        ):
+            # The controller located a volume attached since this client's
+            # last membership refresh (an autoscale attach racing this get):
+            # adopt the new fleet before building per-volume requests.
+            await self._refresh_health()
         inplace_ok = self._transports_support_inplace(located)
         for idx, req in enumerate(requests):
             subs = self._build_volume_requests(

@@ -557,11 +557,6 @@ ENV_REGISTRY: tuple[EnvVar, ...] = (
     EnvVar("TORCHSTORE_TPU_BENCH_COLD_MB", "int", None,
            "bench.py cold-path working-set size in MB (default scales with "
            "the bench tensor set)."),
-    EnvVar("TORCHSTORE_TPU_BENCH_DEVICE", "str", "1",
-           "Set 0/false to skip bench.py device phases."),
-    EnvVar("TORCHSTORE_TPU_BENCH_DEVICE_ALLOW_CPU", "bool", False,
-           "Allow bench.py device phases on CPU jax (interpret mode) "
-           "instead of refusing."),
 )
 
 # Dynamic families: names extending these prefixes are per-instance handles

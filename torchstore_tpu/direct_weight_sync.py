@@ -315,23 +315,24 @@ class DirectWeightSyncSource:
             self._busy += 1 if on else -1
 
     def _device_mode_eligible(self, flat: dict) -> bool:
-        """Device path engages when every tensor leaf lives on device: plain
-        jax arrays, or rank-local ``Shard`` wrappers whose data is a jax
-        array. Rank-independent: each rank of a multi-rank SPMD source
-        registers its own per-shard device entries (``register``'s rank
-        param — the reference's per-rank handle publication pattern,
-        state_dict_utils.py:217-275)."""
+        """Device path engages when every tensor leaf lives on a device the
+        transfer engine serves (``device_transfer.serves`` — not a TPU, on
+        this installation): plain jax arrays, or rank-local ``Shard``
+        wrappers whose data is a jax array. Rank-independent: each rank of
+        a multi-rank SPMD source registers its own per-shard device entries
+        (``register``'s rank param — the reference's per-rank handle
+        publication pattern, state_dict_utils.py:217-275)."""
         if self.device is False:
             return False
         if not self.config.ici_enabled:
             return False
         from torchstore_tpu.transport import device_transfer as dt
 
-        if not dt.is_available():
-            return False
-        tensorish = [v for v in flat.values() if _is_tensor_leaf(v)]
+        tensorish = [
+            _unwrap_shard(v) for v in flat.values() if _is_tensor_leaf(v)
+        ]
         return bool(tensorish) and all(
-            shd.is_jax_array(_unwrap_shard(v)) for v in tensorish
+            shd.is_jax_array(x) and dt.serves(x) for x in tensorish
         )
 
     async def register(
@@ -363,7 +364,7 @@ class DirectWeightSyncSource:
                 and shd.is_jax_array(value)
                 and _is_floating(value)
             ):
-                # Cast on device (ops.device_cast: fused XLA / pallas kernel)
+                # Cast on device (ops.device_cast: one fused XLA kernel)
                 # so the HBM->host copy moves the transfer dtype's bytes.
                 from torchstore_tpu.ops import device_cast
 
@@ -591,7 +592,10 @@ class DirectWeightSyncSource:
                 copy_into(staged, host_arr)
                 host_arr = staged
             else:
-                self.server.buffers[buffer_id] = host_arr
+                # np.asarray of a jax array is a READ-ONLY view of the
+                # array's cached host copy: the staging buffer the next
+                # generation lands in must be a writable copy of its own.
+                host_arr = self.server.buffers[buffer_id] = host_arr.copy()
             handles.setdefault(flat_key, []).append(
                 WeightHandle(
                     buffer_id=buffer_id,

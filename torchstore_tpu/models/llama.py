@@ -277,10 +277,9 @@ def _attend(cfg: LlamaConfig, q, k, v):
         and cfg.mesh.shape["sp"] > 1
     )
     if not use_sp:
-        # Inside jit, XLA's fused flash attention runs near MXU peak
-        # (~290 TFLOP/s on v5e at these shapes) and beats our pallas kernel
-        # (~120 TFLOP/s; see ops/flash_attention.py) — so the model's dense
-        # path stays on the XLA kernel. GQA handled natively.
+        # The model's dense path stays on XLA's own attention (GQA handled
+        # natively); how it compares with the pallas kernel on a chip is
+        # not measured (see ops/flash_attention.py).
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     from torchstore_tpu.ops._sharded import make_sharded_attention
     from torchstore_tpu.ops.ring_attention import ring_attention

@@ -1,15 +1,21 @@
 """ctypes bindings for the native data-path library (native/libtsnative.so).
 
-Fail-open: when the library is absent we attempt one `make` build (the
-toolchain is part of the deployment image); if that fails, every helper
-falls back to numpy — the store stays fully functional, just slower. Gated
-by ``StoreConfig.use_native`` / TORCHSTORE_TPU_USE_NATIVE.
+This module builds the library from ``native/tsnative.cc`` itself: at first
+use where it is missing, and again whenever it is older than the source
+(the ``.so`` is git-ignored, so a fresh checkout always builds). The loaded
+library must report ``VERSION``, the one ``tsnative.cc`` produces and these
+bindings are written for; a build that fails, a library that does not load
+and a version that differs all raise. Only a host WITHOUT the toolchain
+(no ``make``/``g++``, no Makefile — e.g. a wheel install) runs on numpy,
+with a warning: the store stays fully functional, just slower. Gated by
+``StoreConfig.use_native`` / TORCHSTORE_TPU_USE_NATIVE.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 from typing import Optional
 
@@ -22,45 +28,58 @@ logger = get_logger("torchstore_tpu.native")
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libtsnative.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "tsnative.cc")
+
+# What native/tsnative.cc's ts_version() returns.
+VERSION = 3
 
 # Below this size the ctypes call overhead beats the threading win.
 PARALLEL_THRESHOLD = 8 * 1024 * 1024
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
-# True once a v2+ library bound the threaded-prefault entry (v1 binaries
-# carry an incompatible 2-arg ts_prefault that must never be called).
-_has_prefault = False
-# True once a v3+ library bound the batched scatter memcpy.
-_has_copy_batch = False
 
 
-def _try_build() -> bool:
-    """Build the library once, under a cross-process file lock so N actor
-    processes starting together don't race `make` (a loser could otherwise
-    dlopen a half-written .so). Called from initialize()/volume startup, not
-    from the transfer hot path."""
-    makefile = os.path.join(_NATIVE_DIR, "Makefile")
-    if not os.path.exists(makefile) or not os.access(_NATIVE_DIR, os.W_OK):
+def _stale() -> bool:
+    """True when the library is missing or older than its source."""
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return not os.path.exists(_LIB_PATH)
+
+
+def _build() -> bool:
+    """(Re)build a missing or stale library, under a cross-process file lock
+    so N actor processes starting together don't race `make` (a loser could
+    otherwise dlopen a half-written .so). Called from initialize()/volume
+    startup, not from the transfer hot path. Returns False only where there
+    is nothing to build with; a build that runs and fails raises."""
+    if (
+        not os.path.exists(os.path.join(_NATIVE_DIR, "Makefile"))
+        or not os.access(_NATIVE_DIR, os.W_OK)
+        or shutil.which("make") is None
+        or shutil.which(os.environ.get("CXX", "g++")) is None
+    ):
         return False
     import fcntl
 
-    lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
-    try:
-        with open(lock_path, "w") as lock_file:
-            fcntl.flock(lock_file, fcntl.LOCK_EX)
-            if os.path.exists(_LIB_PATH):  # another process built it
-                return True
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True,
-                capture_output=True,
-                timeout=60,
-            )
-            return os.path.exists(_LIB_PATH)
-    except Exception as exc:
-        logger.warning("native build failed (falling back to numpy): %s", exc)
-        return False
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock_file:
+        fcntl.flock(lock_file, fcntl.LOCK_EX)
+        if _stale():  # not already rebuilt by another process
+            try:
+                # -B: staleness is decided above, not by make's own rule.
+                subprocess.run(
+                    ["make", "-B", "-C", _NATIVE_DIR],
+                    check=True,
+                    capture_output=True,
+                    text=True,
+                    timeout=120,
+                )
+            except subprocess.CalledProcessError as exc:
+                raise RuntimeError(
+                    f"building {_LIB_PATH} failed:\n{exc.stderr}"
+                ) from exc
+    return True
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -70,64 +89,50 @@ def get_lib() -> Optional[ctypes.CDLL]:
     _load_attempted = True
     if not default_config().use_native:
         return None
-    if not os.path.exists(_LIB_PATH) and not _try_build():
+    if _stale() and not _build() and not os.path.exists(_LIB_PATH):
+        logger.warning(
+            "no native library and no toolchain to build it in %s; "
+            "copies run on numpy",
+            _NATIVE_DIR,
+        )
         return None
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-        lib.ts_parallel_memcpy.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int,
-        ]
-        lib.ts_parallel_memcpy.restype = None
-        lib.ts_copy_2d.argtypes = [
-            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p,
-            ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int,
-        ]
-        lib.ts_copy_2d.restype = None
-        lib.ts_read_fd.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64]
-        lib.ts_read_fd.restype = ctypes.c_int64
-        lib.ts_write_fd.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64]
-        lib.ts_write_fd.restype = ctypes.c_int64
-        lib.ts_version.restype = ctypes.c_uint32
-        version = lib.ts_version()
-        assert version in (1, 2, 3), version
-        if version >= 2:
-            # v2: multi-threaded page prefault (the provisioning subsystem's
-            # prewarm entry). v1 binaries carry an incompatible 2-arg
-            # ts_prefault — never bind it there.
-            lib.ts_prefault.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int,
-            ]
-            lib.ts_prefault.restype = ctypes.c_int
-            global _has_prefault
-            _has_prefault = True
-        else:
-            logger.info("native library is v1 (no threaded prefault)")
-        if version >= 3:
-            # v3: batched scatter memcpy (the one-sided warm get's landing
-            # loop). v2 binaries fall back to the per-pair Python loop.
-            lib.ts_copy_batch.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_uint64, ctypes.c_int,
-            ]
-            lib.ts_copy_batch.restype = None
-            global _has_copy_batch
-            _has_copy_batch = True
-        _lib = lib
-        logger.info("native data path loaded (%s)", _LIB_PATH)
-    except Exception as exc:
-        logger.warning("native library unusable, using numpy fallback: %s", exc)
-        _lib = None
+    lib = ctypes.CDLL(_LIB_PATH)
+    lib.ts_version.restype = ctypes.c_uint32
+    version = lib.ts_version()
+    if version != VERSION:
+        raise RuntimeError(
+            f"{_LIB_PATH} is version {version}, these bindings need "
+            f"{VERSION}: remove it so it is rebuilt from tsnative.cc"
+        )
+    lib.ts_parallel_memcpy.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int,
+    ]
+    lib.ts_parallel_memcpy.restype = None
+    lib.ts_copy_2d.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p,
+        ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_int,
+    ]
+    lib.ts_copy_2d.restype = None
+    lib.ts_read_fd.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64]
+    lib.ts_read_fd.restype = ctypes.c_int64
+    lib.ts_write_fd.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64]
+    lib.ts_write_fd.restype = ctypes.c_int64
+    # Multi-threaded page prefault (the provisioning subsystem's prewarm).
+    lib.ts_prefault.argtypes = [ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int]
+    lib.ts_prefault.restype = ctypes.c_int
+    # Batched scatter memcpy (the one-sided warm get's landing loop).
+    lib.ts_copy_batch.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_uint64, ctypes.c_int,
+    ]
+    lib.ts_copy_batch.restype = None
+    _lib = lib
+    logger.info("native data path loaded (%s)", _LIB_PATH)
     return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
-
-
-def copy_batch_available() -> bool:
-    """True when the v3 batched scatter memcpy is bound (callers build the
-    pointer arrays only when the call can actually happen)."""
-    return get_lib() is not None and _has_copy_batch
 
 
 def _addr(arr: np.ndarray) -> int:
@@ -190,9 +195,9 @@ def copy_batch(
     caller OWNS eligibility: every pair must be same-size, both sides
     C-contiguous, and non-overlapping (the landing layer checks this).
     Arrays must be uint64 and C-contiguous. Returns False when the library
-    is absent or pre-v3 — the caller runs its per-pair Python loop."""
+    is absent — the caller runs its per-pair Python loop."""
     lib = get_lib()
-    if lib is None or not _has_copy_batch:
+    if lib is None:
         return False
     n = len(lens)
     if n == 0:
@@ -208,10 +213,10 @@ def prefault(addr: int, length: int, nthreads: int = 0) -> bool:
     """Multi-threaded prefault of ``length`` bytes at ``addr`` (one write per
     page, spread over ``nthreads``; 0 = auto). Returns True when the native
     path ran; False means the caller must fall back to touching pages itself
-    (v1 library or numpy-only build). Used by the provisioning subsystem to
-    pre-allocate tmpfs segment pages off the first-sync critical path."""
+    (numpy-only host). Used by the provisioning subsystem to pre-allocate
+    tmpfs segment pages off the first-sync critical path."""
     lib = get_lib()
-    if lib is None or not _has_prefault:
+    if lib is None:
         return False
     if length <= 0:
         return True

@@ -5,7 +5,7 @@
 Set ``TORCHSTORE_TPU_METRICS_PORT`` and every torchstore process starts a
 stdlib ``http.server`` thread serving its own registry in Prometheus text —
 ``curl host:PORT/metrics`` scrapes a LIVE run instead of waiting for the
-periodic file dump, and ``/healthz`` gives tpu_watch.sh / load balancers a
+periodic file dump, and ``/healthz`` gives load balancers a
 liveness probe (200 + JSON with pid/uptime).
 
 Port contention is expected, not an error: volume actors inherit the same

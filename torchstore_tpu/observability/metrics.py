@@ -379,8 +379,8 @@ def _resolve_dump_path(base: str) -> str:
     """Claim ``base`` for this process; concurrent processes (volume actors
     dump too) take a pid-suffixed sibling. Ownership is arbitrated through a
     ``<base>.owner`` sidecar recording the claimant's pid — NOT the dump
-    file's existence: dumps persist across runs (tpu_watch reuses its
-    OUTDIR), and a leftover file from a finished run must not divert a
+    file's existence: dumps persist across runs (output directories are
+    reused), and a leftover file from a finished run must not divert a
     fresh run to a suffixed sibling while the base path serves stale data.
     A dead owner's claim is taken over; writes are atomic whole-file
     replaces, so even a (rare) double-takeover cannot interleave output."""

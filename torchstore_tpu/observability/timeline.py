@@ -20,7 +20,7 @@ signals:
   the question an end-to-end timer can't: *which stage ate the budget*
   (:func:`dominant_stage`). Stage names MUST come from :data:`STAGE_CATALOG`
   — the ``stage-discipline`` tslint rule holds client and volume sites to
-  the same taxonomy so digests from both sides fold together.
+  the same stage catalog so digests from both sides fold together.
 
 - **SLO thresholds** (``TORCHSTORE_TPU_SLO_*``): a typed family of
   operator-set bars. On breach the violation is logged (rate-limited per
@@ -77,7 +77,7 @@ SLO_OVERLAP_MIN = "TORCHSTORE_TPU_SLO_OVERLAP_MIN"
 
 # The registered stage catalog. Every wall-clock segment recorded into the
 # stage digests — client-side or volume-side — names one of these, so
-# digests from both ends of a transfer fold into the same taxonomy (the
+# digests from both ends of a transfer fold into the same stage catalog (the
 # ``stage-discipline`` tslint rule rejects free-string stage labels):
 #
 #   plan            metadata resolve: locate (RPC or stamped), plan/epoch

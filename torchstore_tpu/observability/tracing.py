@@ -46,7 +46,7 @@ ENV_TRACE = "TORCHSTORE_TPU_TRACE"
 # trace file, inherited by every actor child through the TORCHSTORE_TPU_*
 # env forwarding. Distinguishes "sibling of this run already exited" (its
 # events must survive into the merge) from "leftover file of a FINISHED
-# run" (must be cleared, or tpu_watch's reused OUTDIR merges dead spans).
+# run" (must be cleared, or a reused output directory merges dead spans).
 ENV_TRACE_RUN = "TORCHSTORE_TPU_TRACE_RUN"
 
 
@@ -150,7 +150,7 @@ class TraceCollector:
         # the claimant's pid (same arbitration as the metrics dumper): a
         # LIVE concurrent process owning it sends us to a pid-suffixed
         # sibling, but a leftover file from a FINISHED run is taken over and
-        # truncated — tpu_watch reuses its OUTDIR across runs, and a stale
+        # truncated — output directories are reused across runs, and a stale
         # base full of dead spans must not pollute the next merge. The pid
         # path is always truncated on claim: any existing content is ours
         # from a previous resolution or a recycled pid's dead run, and

@@ -26,35 +26,18 @@ def make_sharded_attention(
     collectives loudly). Cached so repeat calls reuse the compiled
     executable."""
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     kwargs = {"axis_name": axis_name, "causal": causal}
     if impl is not None:
         kwargs["impl"] = impl
     spec = P(None, axis_name, head_axis, None)
-    sm_kwargs = dict(
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    fn = shard_map(
+        functools.partial(body, **kwargs),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=not relax_vma,
     )
-    if relax_vma:
-        # The relax knob was renamed across jax versions (check_rep ->
-        # check_vma); try the current name first, then the older one. Bodies
-        # running pallas kernels need ONE of them off, or shard_map's
-        # replication checker rejects pallas_call outright.
-        for kw in ("check_vma", "check_rep"):
-            try:
-                fn = shard_map(
-                    functools.partial(body, **kwargs), **{kw: False}, **sm_kwargs
-                )
-                break
-            except TypeError:  # this jax doesn't know the kwarg
-                continue
-        else:  # neither name exists: run with checking on
-            fn = shard_map(functools.partial(body, **kwargs), **sm_kwargs)
-    else:
-        fn = shard_map(functools.partial(body, **kwargs), **sm_kwargs)
     return jax.jit(fn)

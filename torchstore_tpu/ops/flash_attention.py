@@ -7,13 +7,12 @@
   per block; XLA's fused attention cannot emit those, so a bespoke kernel
   is the only way to run ring hops without materializing (sq, sk) score
   tensors in HBM.
-- **Explicitly NOT the production dense kernel**: whole-sequence
-  ``flash_attention`` measures ~120 TFLOP/s on v5e vs ~290 for XLA's own
-  fused attention at the same shapes (BASELINE.md) — the model's dense
-  path therefore uses ``jax.nn.dot_product_attention``
-  (models/llama.py:_attend), and this module's normalized entry remains as
-  the stats kernel's differential-test twin (same block body, one extra
-  normalization) and the off-TPU interpret-mode reference.
+- **Explicitly NOT the production dense kernel**: the model's dense path
+  uses ``jax.nn.dot_product_attention`` (models/llama.py:_attend). How
+  whole-sequence ``flash_attention`` compares with it on a v5e chip is not
+  measured; this module's normalized entry remains as the stats kernel's
+  differential-test twin (same block body, one extra normalization) and
+  the off-TPU interpret-mode reference.
 
 Mechanics: q/k/v stream through VMEM in (block_q x d) / (block_k x d)
 tiles, scores hit the MXU via ``dot_general`` in fp32, and the
@@ -30,8 +29,9 @@ per-device block math. ``ring_attention``'s fused body invokes
 unnormalized accumulator plus the online-softmax running max/denominator)
 once per ring hop and merges the per-block stats across hops;
 ``ulysses_attention`` runs whole-sequence attention per head shard. Off-TPU
-the kernels run in interpret mode (tested against dense attention); on TPU
-they compile to fused VMEM-resident loops.
+the kernels run in interpret mode (tested against dense attention); on a
+TPU backend they always compile to fused VMEM-resident loops
+(``_interpret_mode``).
 
 Layout: (batch, seq, heads, head_dim) in, same out. GQA maps kv heads via
 the BlockSpec index maps (no repetition). Block sizes must divide the
@@ -45,6 +45,22 @@ import functools
 import math
 
 NEG_INF = -1e30
+
+
+def _interpret_mode(interpret: "bool | None") -> bool:
+    """Resolve a kernel entry's ``interpret`` argument. Interpret mode
+    exists for the off-TPU tests: ``None`` picks it exactly when the
+    process's jax backend is not a TPU, and a caller on a TPU never gets
+    it. Code compiling for a described (unattached) TPU topology still sees
+    the CPU backend here and passes ``interpret=False`` explicitly."""
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("pallas interpret mode requested on a TPU backend")
+    return interpret
 
 
 def _kernel(
@@ -173,16 +189,7 @@ def _flash_call(
     def out_sds(shape, dtype):
         # Under shard_map with vma checking, pallas out_shapes must declare
         # which mesh axes the output varies over — same set as the inputs.
-        try:
-            vma = jax.typeof(qf).vma
-        except AttributeError:
-            vma = None
-        if vma:
-            try:
-                return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-            except TypeError:  # older jax: no vma kwarg
-                pass
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(qf).vma)
 
     if emit_stats:
         out_specs = [
@@ -365,8 +372,6 @@ def flash_attention_stats(q, k, v, causal_diag: bool = False, interpret=None):
     ``m' = max(m1, m2); acc' = acc1*e^(m1-m') + acc2*e^(m2-m')`` etc.
     Differentiable: backward recomputes the block densely (see
     ``_stats_diff``)."""
-    import jax
-
     block_q = _pick_block(q.shape[1])
     block_k = _pick_block(k.shape[1])
     if block_q is None or block_k is None:
@@ -374,9 +379,9 @@ def flash_attention_stats(q, k, v, causal_diag: bool = False, interpret=None):
             f"sequence lengths {q.shape[1]}/{k.shape[1]} don't tile; gate "
             "with flash_stats_eligible()"
         )
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    return _stats_diff(causal_diag, block_q, block_k, interpret)(q, k, v)
+    return _stats_diff(
+        causal_diag, block_q, block_k, _interpret_mode(interpret)
+    )(q, k, v)
 
 
 def flash_attention(
@@ -405,6 +410,6 @@ def flash_attention(
         or (causal and sq > sk)
     ):
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal)
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    return _jitted(causal, block_q, block_k, interpret)(q, k, v)
+    return _jitted(causal, block_q, block_k, _interpret_mode(interpret))(
+        q, k, v
+    )

@@ -176,23 +176,17 @@ def _ring_einsum(q, k, v, axis_name: str, causal: bool):
     o0 = jnp.zeros((b, hk, g, sq, d), jnp.float32)
     m0 = jnp.full((b, hk, g, sq), NEG)
     l0 = jnp.zeros((b, hk, g, sq), jnp.float32)
-    o0, m0, l0 = (_mark_varying(lax, x, axis_name) for x in (o0, m0, l0))
+    # shard_map tracks device-varying types through loop carries: constant
+    # initializers must be marked varying over the ring axis.
+    o0, m0, l0 = (
+        lax.pcast(x, axis_name, to="varying") for x in (o0, m0, l0)
+    )
     # Step 0: own (unrotated) block, outside the loop.
     o0, m0, l0 = accumulate((o0, m0, l0), k, v, 0)
     o, m, l, _, _ = lax.fori_loop(1, n, step, (o0, m0, l0, k, v))
     out = o / jnp.maximum(l, 1e-30)[..., None]  # (b,hk,g,sq,d)
     out = jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, sq, h, d)
     return out.astype(q.dtype)
-
-
-def _mark_varying(lax, x, axis_name: str):
-    """Newer shard_map tracks device-varying types through scan carries;
-    constant initializers must be marked varying over the ring axis."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_name)
-    return x  # older jax: no varying-type tracking
 
 
 def ring_attention_sharded(

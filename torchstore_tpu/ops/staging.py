@@ -1,17 +1,15 @@
-"""Device-side staging ops: dtype cast for weight transfer.
+"""Device-side staging op: dtype cast for weight transfer.
 
 The transfer-dtype cast (fp32 -> bf16 before shipping weights,
 /root/reference/torchstore/state_dict_utils.py:177-189 does it on host with
-torch) runs on-device here so the HBM->host copy moves half the bytes. Two
-paths:
+torch) runs on-device here so the HBM->host copy moves half the bytes.
 
-- ``device_cast``: jitted ``astype`` with buffer donation — XLA emits a
-  single fused convert kernel; this is the default (the compiler already
-  does the right thing for a pure elementwise op).
-- ``pallas_cast``: the same op as an explicit Pallas TPU kernel, tiled to
-  the VPU lane layout. Exists as the template for future fused staging
-  kernels (cast+pack, cast+reduce) where XLA fusion is not enough; falls
-  back to interpret mode off-TPU so it is testable on the CPU mesh.
+``device_cast`` is a jitted ``astype``: XLA emits one fused convert kernel.
+A hand-written Pallas cast tiled to one (8, 128) VPU tile per grid step was
+measured against it on a TPU v5 lite chip at the Llama-3-8B MLP shape
+(4096 x 14336, f32 -> bf16; my chip run, PR 21): 1.19 ms for the jitted
+``astype`` against 69.9 ms for the Pallas kernel's 57 344 grid steps, so the
+kernel was removed and nothing here falls back to anything.
 """
 
 from __future__ import annotations
@@ -32,55 +30,10 @@ def _cast_fn(dtype_str: str):
 
 
 def device_cast(x, dtype):
-    """On-device dtype cast (one fused XLA kernel; pallas-tiled on TPU when
-    the shape allows). Used by the direct-sync source so the HBM->host copy
-    moves the transfer dtype's bytes, not the param dtype's."""
-    import jax
+    """On-device dtype cast (one fused XLA kernel). Used by the direct-sync
+    source so the HBM->host copy moves the transfer dtype's bytes, not the
+    param dtype's."""
     import numpy as np
 
     dtype_str = str(np.dtype(dtype) if isinstance(dtype, type) else dtype)
-    if jax.devices()[0].platform == "tpu":
-        try:
-            return pallas_cast(x, dtype_str, interpret=False)
-        except Exception:  # pragma: no cover - pallas availability varies
-            pass
     return _cast_fn(dtype_str)(x)
-
-
-# Tile shape aligned to the TPU VPU (8 sublanes x 128 lanes).
-_TILE = (8, 128)
-
-
-def pallas_cast(x, dtype, interpret: bool | None = None):
-    """Pallas cast kernel for 2D-tileable arrays; falls back to
-    ``device_cast`` when the shape doesn't tile cleanly."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    out_dtype = jnp.dtype(dtype)
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    cols = _TILE[0] * _TILE[1]
-    if n % cols != 0:
-        # Unaligned shapes take the plain fused-XLA cast (NOT device_cast,
-        # which would recurse back here on TPU).
-        return _cast_fn(str(out_dtype))(x)
-    rows = n // _TILE[1]
-    x2d = flat.reshape(rows, _TILE[1])
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-
-    def kernel(x_ref, o_ref):
-        o_ref[...] = x_ref[...].astype(out_dtype)
-
-    grid = (rows // _TILE[0],)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec(_TILE, lambda i: (i, 0))],
-        out_specs=pl.BlockSpec(_TILE, lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _TILE[1]), out_dtype),
-        interpret=interpret,
-    )(x2d)
-    return out.reshape(x.shape)

@@ -60,24 +60,46 @@ def logical_to_mesh_axes(logical_axes, mesh, rules=DEFAULT_RULES):
     return PartitionSpec(*out)
 
 
+def _is_boxed(leaf) -> bool:
+    from flax.core import meta
+
+    return isinstance(leaf, meta.Partitioned)
+
+
+def param_shardings(params, mesh, rules=DEFAULT_RULES):
+    """The ``NamedSharding`` of every param on ``mesh``, as a tree shaped
+    like ``unbox(params)``: logical-axis metadata (flax
+    ``nn.with_logical_partitioning``) resolved through ``rules``; params
+    without metadata replicate. Needs only the boxes, so the abstract tree
+    of ``jax.eval_shape(model.init, ...)`` does — its result is what a
+    jitted init takes as ``out_shardings`` to create params already
+    sharded."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def sharding(leaf):
+        spec = (
+            logical_to_mesh_axes(leaf.names, mesh, rules)
+            if _is_boxed(leaf)
+            else PartitionSpec()
+        )
+        return NamedSharding(mesh, spec)
+
+    return jax.tree.map(sharding, params, is_leaf=_is_boxed)
+
+
 def shard_params(params, mesh, rules=DEFAULT_RULES):
     """Apply logical-axis metadata (flax ``nn.with_logical_partitioning``) to
     place a param pytree on the mesh; params without metadata replicate."""
     import jax
-    from flax.core import meta
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    def place(leaf):
-        if isinstance(leaf, meta.Partitioned):
-            spec = logical_to_mesh_axes(leaf.names, mesh, rules)
-            value = leaf.value
-        else:
-            spec = PartitionSpec()
-            value = leaf
-        return jax.device_put(value, NamedSharding(mesh, spec))
 
     return jax.tree.map(
-        place, params, is_leaf=lambda x: isinstance(x, meta.Partitioned)
+        lambda leaf, sharding: jax.device_put(
+            leaf.value if _is_boxed(leaf) else leaf, sharding
+        ),
+        params,
+        param_shardings(params, mesh, rules),
+        is_leaf=_is_boxed,
     )
 
 

@@ -90,7 +90,10 @@ async def prewarm_manifest(
     arrays: Optional[list] = None,
 ) -> dict:
     """Provision every layer a sync of ``manifest`` will touch. Returns a
-    report dict; never raises. ``direct=True`` additionally pre-creates the
+    report dict. Host legs never raise (the lazy path serves what they
+    could not warm); the device leg does, because the transfer server has
+    no lazy stand-in — if it cannot start here it cannot start at the
+    direct register either. ``direct=True`` additionally pre-creates the
     client-local staging segments a direct-source ``register`` will draw.
     ``arrays`` (optional, real source buffers) feed the bulk registration
     cache."""
@@ -109,6 +112,7 @@ async def prewarm_manifest(
         "errors": {},
     }
     _RUNS.inc()
+    plan = None
     try:
         with obs_context.ensure_root(), span(
             "provision.prewarm",
@@ -119,8 +123,6 @@ async def prewarm_manifest(
             if plan is not None:
                 reservation = await _reserve(client, plan, report)
                 await _run_volume_legs(client, plan, report)
-                if plan.device_server:
-                    _run_device_leg(report)
                 if reservation is not None:
                     try:
                         await client.controller.release_prewarm.call_one(
@@ -137,6 +139,10 @@ async def prewarm_manifest(
         # the put_state_dict path — a caller's wait_for timeout must still
         # cancel it) and interpreter exits propagate.
         _fail(report, "prewarm", exc)
+    if plan is not None and plan.device_server:
+        from torchstore_tpu.transport import device_transfer as dt
+
+        report["device_server"] = dt.prewarm_engine()
     return report
 
 
@@ -153,7 +159,6 @@ async def _build_plan(client, manifest, report):
             except Exception:  # noqa: BLE001 - strategy without env context
                 client_id = volume_ids[0]
             put_ids = strategy.select_put_volume_ids(client_id, volume_ids)
-            from torchstore_tpu.transport import device_transfer as dt
             from torchstore_tpu.transport.factory import create_transport_buffer
 
             transports = {
@@ -166,7 +171,7 @@ async def _build_plan(client, manifest, report):
                 manifest,
                 put_ids,
                 transports,
-                ici_available=client._config.ici_enabled and dt.is_available(),
+                ici_available=client._config.ici_enabled,
                 arena_max_bytes=client._config.arena_max_bytes,
             )
             # Plan-cache handoff: hand the provisioned arena layout to the
@@ -280,15 +285,6 @@ async def _run_volume_legs(client, plan, report) -> None:
             _fail(report, f"volume:{vid}", result)
 
 
-def _run_device_leg(report) -> None:
-    try:
-        from torchstore_tpu.transport import device_transfer as dt
-
-        report["device_server"] = dt.prewarm_engine()
-    except Exception as exc:  # noqa: BLE001
-        _fail(report, "device", exc)
-
-
 async def _run_local_staging_leg(client, manifest, report) -> None:
     """Pre-create the client-local staging segments a direct-source
     register() will draw (one exact-size segment per request). The creation
@@ -350,7 +346,8 @@ async def maybe_auto_prewarm(client, flat: dict) -> Optional[dict]:
     """The put_state_dict hint path: derive a manifest from the already-
     flattened dict and provision ahead of the first commit. Gated by
     ``config.prewarm_auto`` and ``prewarm_auto_min_bytes``; fires at most
-    once per distinct size-signature per client; never raises."""
+    once per distinct size-signature per client. Like ``prewarm_manifest``
+    it raises only from the device leg."""
     try:
         config = getattr(client, "_config", None)
         if config is None or not getattr(config, "prewarm_auto", False):
@@ -376,17 +373,6 @@ async def maybe_auto_prewarm(client, flat: dict) -> Optional[dict]:
             return None
         seen.add(signature)
         manifest = StateDictManifest.from_state_dict(flat)
-        report = await prewarm_manifest(client, manifest)
-        logger.info(
-            "auto-prewarm: %d entries / %d bytes -> %d segment(s), "
-            "%d dial(s)%s",
-            report["entries"],
-            report["manifest_bytes"],
-            report["segments"],
-            report["dials"],
-            " (with errors)" if report["errors"] else "",
-        )
-        return report
     except Exception as exc:  # noqa: BLE001 - the put must proceed
         _fail(
             {"ok": False, "errors": {}},
@@ -394,3 +380,14 @@ async def maybe_auto_prewarm(client, flat: dict) -> Optional[dict]:
             exc,
         )
         return None
+    report = await prewarm_manifest(client, manifest)
+    logger.info(
+        "auto-prewarm: %d entries / %d bytes -> %d segment(s), "
+        "%d dial(s)%s",
+        report["entries"],
+        report["manifest_bytes"],
+        report["segments"],
+        report["dials"],
+        " (with errors)" if report["errors"] else "",
+    )
+    return report

@@ -45,8 +45,8 @@ class StateDictManifest:
     dials, and transfer plans before the first byte moves."""
 
     entries: list[ManifestEntry] = field(default_factory=list)
-    # True when any tensor leaf is a device-resident jax array: the ICI rung
-    # (transfer server) is worth prewarming too.
+    # True when any tensor leaf is a jax array the device rung can serve
+    # (device_transfer.serves): the transfer server is worth prewarming too.
     device_resident: bool = False
     # Flat keys in the SOURCE dict's insertion order — for a model state
     # dict this is model-forward order (flatten preserves dict iteration
@@ -218,6 +218,7 @@ def _entry_of(
     from torchstore_tpu import sharding as shd
     from torchstore_tpu import torch_interop
     from torchstore_tpu.client import Shard
+    from torchstore_tpu.transport import device_transfer
 
     if transfer_quant is not None:
         entry, on_device = _entry_of(key, value, None)
@@ -251,7 +252,7 @@ def _entry_of(
         shape = tuple(int(s) for s in value.shape)
         dtype = str(value.dtype)
         itemsize = _transfer_itemsize(dtype, transfer_dtype)
-        on_device = shd.is_jax_array(value)
+        on_device = shd.is_jax_array(value) and device_transfer.serves(value)
         sharding = getattr(value, "sharding", None)
         if sharding is None or shd._is_demotable(sharding):
             count = int(np.prod(shape)) if shape else 1

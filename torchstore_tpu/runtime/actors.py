@@ -714,6 +714,21 @@ def _mp_context() -> mp.context.BaseContext:
     return _ctx
 
 
+def stop_spawn_helpers() -> None:
+    """Stop multiprocessing's fork server and resource tracker and wait for
+    both. They outlive every ``ActorMesh.stop()`` on purpose (the next spawn
+    reuses the warm fork server) and otherwise exit only AFTER this process
+    has: a program that must leave no process behind when it ends calls this
+    once its last store is shut down. The next spawn starts them again."""
+    global _ctx
+    from multiprocessing import forkserver, resource_tracker
+
+    # The fork server holds the tracker's pipe open: it goes first.
+    forkserver._forkserver._stop()
+    resource_tracker._resource_tracker._stop()
+    _ctx = None  # the next _mp_context() relaunches with its env stripping
+
+
 async def spawn_actors(
     num_actors: int,
     actor_cls: type,

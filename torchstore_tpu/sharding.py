@@ -88,7 +88,7 @@ def put_requests(key: str, x) -> list[Request]:
 
     sharding = x.sharding
     if _is_demotable(sharding):
-        _start_d2h(x)
+        x.copy_to_host_async()
         return [Request.from_tensor(key, np.asarray(x))]
     mesh = sharding.mesh
     mesh_shape = tuple(int(s) for s in mesh.devices.shape)
@@ -96,7 +96,7 @@ def put_requests(key: str, x) -> list[Request]:
     global_shape = tuple(int(s) for s in x.shape)
     shards = list(x.addressable_shards)
     for shard in shards:
-        _start_d2h(shard.data)
+        shard.data.copy_to_host_async()
     requests = []
     for shard in shards:
         data = np.asarray(shard.data)
@@ -110,18 +110,6 @@ def put_requests(key: str, x) -> list[Request]:
         )
         requests.append(Request.from_tensor_slice(key, ts, data))
     return requests
-
-
-def _start_d2h(arr) -> None:
-    """Kick off the async device->host copy for ``arr`` (no-op when the
-    runtime lacks it); a later np.asarray then finds the bytes already in
-    flight or landed."""
-    start = getattr(arr, "copy_to_host_async", None)
-    if start is not None:
-        try:
-            start()
-        except Exception:  # pragma: no cover - backend without async D2H
-            pass
 
 
 def target_slices(like) -> list[tuple[Any, TensorSlice]]:

@@ -58,10 +58,8 @@ def _axis_metadata_box(value: Any):
     would ride the opaque object path (no resharding, full-serialize puts).
     Flatten unboxes them — the array takes the tensor path — and records the
     empty box in the mapping so unflatten restores the exact structure."""
-    try:
-        from flax.core import meta as flax_meta
-    except ImportError:  # pragma: no cover - flax is in this image
-        return None
+    from flax.core import meta as flax_meta
+
     if isinstance(value, flax_meta.AxisMetadata):
         return value.replace_boxed(None)
     return None
@@ -1281,15 +1279,6 @@ async def _get_state_dict_direct(
     dest, all_handles, device_infos = entry
     try:
         if device_infos is not None:
-            from torchstore_tpu.transport import device_transfer as _dt
-
-            if not _dt.is_available():
-                raise RuntimeError(
-                    f"direct push {key!r} rides the device (ICI) path but "
-                    "this process's jax build lacks the transfer engine; "
-                    "set TORCHSTORE_TPU_ICI_ENABLED=0 on the source to use "
-                    "the host path"
-                )
             return await dest.pull_device(device_infos, user_state_dict)
         # Ordering kwargs only when requested: plain pulls keep the
         # two-argument call shape (test stubs and subclasses rely on it).

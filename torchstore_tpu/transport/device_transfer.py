@@ -69,14 +69,24 @@ _OP_SECONDS = obs_metrics.histogram(
 )
 
 
-def is_available() -> bool:
-    """True when this jax build ships the transfer engine."""
-    try:
-        from jax.experimental import transfer  # noqa: F401
+# Platforms whose buffers the transfer engine of THIS installation (jax
+# 0.9.0, jaxlib 0.9.0, libtpu 0.0.34) serves — decided by runs, not by
+# whether the module imports. On a TPU v5 lite chip (my chip run, PR 21) a
+# server's pulls complete until 2 GiB have passed through it (16 pulls of a
+# 117 MB array; max_num_parallel_copies 8 x transfer_size 256 MiB), then the
+# arrays of the next pull never become ready and the caller blocks forever —
+# Llama-3-8B-width weights (3.85 GB at 4 layers) hang in their first pull.
+# ``use_raw_buffers=True`` changes nothing, and ``supports_pinned_allocator=
+# True`` fails at server start ("NOT_FOUND: tcp-zero-copy-mmap: No such
+# device"). The CPU client serves without such a limit (tier 1). A TPU
+# source therefore takes direct sync's host-staged path.
+SERVED_PLATFORMS = frozenset({"cpu"})
 
-        return hasattr(transfer, "start_transfer_server")
-    except Exception:  # pragma: no cover - jax without the extension
-        return False
+
+def serves(arr) -> bool:
+    """Whether the device rung can serve the jax.Array ``arr``: every device
+    holding it is of a platform the transfer engine serves."""
+    return all(d.platform in SERVED_PLATFORMS for d in arr.sharding.device_set)
 
 
 # --------------------------------------------------------------------------
@@ -314,15 +324,13 @@ def upload_stamped(view, recheck, dtype=None, sharding=None):
     return out
 
 
-def prewarm_engine() -> Optional[str]:
+def prewarm_engine() -> str:
     """Cold-start provisioning for the ICI rung: start this process's
     transfer server BEFORE the first publish/pull needs it (server startup
     binds a listener and initializes the backend's transfer machinery — paid
     once, and without prewarm it lands on iteration 0's critical path).
-    Returns the server address, or None when this jax build has no transfer
-    engine. Staging itself stays per-pull (the engine's one-shot contract);
-    dest-side staging buffers are the pull targets the caller provides."""
-    if not is_available():
-        return None
+    Returns the server address. Staging itself stays per-pull (the engine's
+    one-shot contract); dest-side staging buffers are the pull targets the
+    caller provides."""
     with tracing.span("provision.device_server"):
         return DeviceTransferEngine.get().ensure_server()

@@ -119,15 +119,13 @@ def create_transport_buffer(
     if not _logged_resolution:
         # One line listing every rung's availability (reference behavior,
         # /root/reference/torchstore/transport/__init__.py:70-81).
-        from torchstore_tpu.transport import device_transfer
-
         logger.info(
             "transport resolution: volume=%s same_host=%s -> %s "
             "[ici(direct)=%s shm=%s bulk=%s rpc=True]",
             volume.volume_id,
             volume.is_same_host(),
             chosen.value,
-            config.ici_enabled and device_transfer.is_available(),
+            config.ici_enabled,
             shm_available(volume, config),
             bulk_available(volume, config),
         )

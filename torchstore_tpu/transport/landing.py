@@ -233,7 +233,7 @@ async def land_batch_async(
 
     from torchstore_tpu import native
 
-    if not native.copy_batch_available():
+    if not native.available():
         return False
     if not lens:
         return True

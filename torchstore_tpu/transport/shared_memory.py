@@ -338,7 +338,7 @@ class ShmSegment:
 
     def prefault(self, nthreads: int = 0) -> None:
         """Touch every page so later copies into this segment never
-        soft-fault. Native multi-threaded path when the v2 library is
+        soft-fault. Native multi-threaded path when the library is
         present; single-thread stride touch otherwise."""
         if self.size == 0:
             return
