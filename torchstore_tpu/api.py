@@ -941,9 +941,9 @@ async def sync_timeline(
 async def slo_report(store_name: Optional[str] = DEFAULT_STORE) -> dict:
     """The live SLO scoreboard: every configured ``TORCHSTORE_TPU_SLO_*``
     threshold with its current value, violation count, violated flag, and
-    — per violated SLO — the dominant stage (plan / transport / landing /
-    stamp_verify / watermark_wait / notify) with the full per-stage
-    wall-time breakdown, so "p99 blew the budget" comes with "and THIS
+    — per violated SLO — the dominant stage (plan / d2h / h2d / transport /
+    landing / stamp_verify / watermark_wait / notify) with the full
+    per-stage wall-time breakdown, so "p99 blew the budget" comes with "and THIS
     stage ate it".
 
     With a ``store_name`` (default store when omitted) the report also

@@ -505,14 +505,16 @@ class LocalClient:
     # ------------------------------------------------------------------
 
     @staticmethod
-    def _value_to_requests(key: str, value: Any) -> list[Request]:
+    def _value_to_requests(
+        key: str, value: Any, d2h: Optional[shd.D2HSeconds] = None
+    ) -> list[Request]:
         if isinstance(value, Shard):
             data = value.data
             if torch_interop.is_torch_tensor(data):
                 data = torch_interop.to_numpy_view(data)
             return [Request.from_tensor_slice(key, value.tensor_slice, data)]
         if shd.is_jax_array(value):
-            return shd.put_requests(key, value)
+            return shd.put_requests(key, value, d2h)
         if isinstance(value, np.ndarray):
             return [Request.from_tensor(key, value)]
         if torch_interop.is_torch_tensor(value):
@@ -601,17 +603,26 @@ class LocalClient:
         # Issue every device->host copy for the WHOLE batch up front so
         # transfers overlap across arrays too, not just across one array's
         # shards (shd.put_requests overlaps within an array).
-        for value in items.values():
-            if shd.is_jax_array(value):
-                for shard in value.addressable_shards:
-                    shard.data.copy_to_host_async()
+        d2h = shd.D2HSeconds()
+        on_device = [v for v in items.values() if shd.is_jax_array(v)]
+        if on_device:
+            shd.issue_d2h(
+                (s.data for v in on_device for s in v.addressable_shards), d2h
+            )
         requests: list[Request] = []
-        for key, value in items.items():
-            requests.extend(self._value_to_requests(key, value))
+        with span("put.requests", keys=len(items)):
+            for key, value in items.items():
+                requests.extend(self._value_to_requests(key, value, d2h))
         volumes = self._put_volumes()
-        # Stage attribution: everything before the first byte moves is the
-        # planning leg (setup, D2H kicks, request building, placement).
-        obs_timeline.observe_stage("put", "plan", tracker.elapsed)
+        # Stage attribution, once per batch: the seconds spent getting
+        # device bytes to the host are the d2h leg; the rest of what runs
+        # before the first byte moves to a volume is the planning leg
+        # (setup, request building, placement).
+        if d2h.seconds:
+            obs_timeline.observe_stage("put", "d2h", d2h.seconds)
+        obs_timeline.observe_stage(
+            "put", "plan", max(tracker.elapsed - d2h.seconds, 0.0)
+        )
         nbytes = sum(r.nbytes for r in requests)
         sp.set(nbytes=nbytes, replicas=len(volumes))
         hot = obs_profile.hot_key_tracker()
@@ -907,102 +918,105 @@ class LocalClient:
         _seed_plan: bool = True,
         prefer_volume: Optional[str] = None,
     ) -> dict[str, Any]:
-        if isinstance(items, str):
-            raise TypeError(
-                "get_batch takes a list of keys or a {key: target} dict, "
-                f"not a bare string ({items!r}); use get() for one key"
-            )
-        if not isinstance(items, dict):
-            items = {key: None for key in items}
-        if self._admission is not None:
-            delay = self._admission.admit(len(items))
-            if delay > 0.0:
-                await asyncio.sleep(delay)
-        await self._ensure_setup()
-        if self._config.one_sided:
-            # Covered warm batch: every member served straight from stamped
-            # SHM segments BEFORE any Request/signature machinery runs —
-            # the many-keys warm get leg is this line plus one native
-            # scatter memcpy (zero RPCs; ISSUE 7 acceptance).
-            served = await self._get_batch_one_sided(items)
-            if served is not None:
-                return served
-        plan: list[tuple[str, Request, Any]] = []  # (key, request, like)
-        # plan index -> device array served one-sided before any request was
-        # built (plain-spec warm path: device_put straight from the stamped
-        # segment view — no host copy, no RPC).
-        pre_served: dict[int, Any] = {}
-        jax_targets: dict[int, list] = {}
-        # plan index -> (original torch tensor, its numpy view): the original
-        # is handed back only when the fetch actually landed in the view.
-        torch_returns: dict[int, tuple[Any, np.ndarray]] = {}
-        requests: list[Request] = []
-        for key, like in items.items():
-            if torch_interop.is_torch_tensor(like):
-                view = torch_interop.to_numpy_view(like, allow_copy=False)
-                torch_returns[len(plan)] = (like, view)
-                like = view
-            if like is None:
-                requests.append(Request.meta_request(key))
-                plan.append((key, requests[-1], None))
-            elif isinstance(like, Shard):
-                data = like.data
-                if torch_interop.is_torch_tensor(data):
-                    view = torch_interop.to_numpy_view(data, allow_copy=False)
-                    torch_returns[len(plan)] = (data, view)
-                    like = Shard(data=view, tensor_slice=like.tensor_slice)
-                req = Request.from_tensor_slice(key, like.tensor_slice)
-                req.tensor_val = like.data
-                requests.append(req)
-                plan.append((key, req, like))
-            elif isinstance(like, TensorSlice):
-                requests.append(Request.from_tensor_slice(key, like))
-                plan.append((key, requests[-1], like))
-            elif shd.is_jax_array(like) or shd.is_sharded_spec(like):
-                # target_slices/build_array only need .shape/.sharding, so a
-                # ShapeDtypeStruct works as a no-allocation restore target.
-                targets = shd.target_slices(like)
-                jax_targets[len(plan)] = targets
-                sub_reqs = [Request.from_tensor_slice(key, ts) for _, ts in targets]
-                requests.extend(sub_reqs)
-                plan.append((key, sub_reqs, like))
-            elif shd.is_plain_spec(like):
-                # Sharding-less ShapeDtypeStruct: fetch the whole tensor and
-                # return a default-placed device array of the spec's dtype.
-                # Warm path first: upload straight from the stamped segment.
-                served = self._try_one_sided_device(key, like)
+        # Per-leaf Python before any byte moves: target slices, request
+        # building, the plan-cache lookup.
+        with span("get.plan", keys=len(items)):
+            if isinstance(items, str):
+                raise TypeError(
+                    "get_batch takes a list of keys or a {key: target} dict, "
+                    f"not a bare string ({items!r}); use get() for one key"
+                )
+            if not isinstance(items, dict):
+                items = {key: None for key in items}
+            if self._admission is not None:
+                delay = self._admission.admit(len(items))
+                if delay > 0.0:
+                    await asyncio.sleep(delay)
+            await self._ensure_setup()
+            if self._config.one_sided:
+                # Covered warm batch: every member served straight from stamped
+                # SHM segments BEFORE any Request/signature machinery runs —
+                # the many-keys warm get leg is this line plus one native
+                # scatter memcpy (zero RPCs; ISSUE 7 acceptance).
+                served = await self._get_batch_one_sided(items)
                 if served is not None:
-                    pre_served[len(plan)] = served
-                    plan.append((key, None, like))
-                else:
+                    return served
+            plan: list[tuple[str, Request, Any]] = []  # (key, request, like)
+            # plan index -> device array served one-sided before any request was
+            # built (plain-spec warm path: device_put straight from the stamped
+            # segment view — no host copy, no RPC).
+            pre_served: dict[int, Any] = {}
+            jax_targets: dict[int, list] = {}
+            # plan index -> (original torch tensor, its numpy view): the original
+            # is handed back only when the fetch actually landed in the view.
+            torch_returns: dict[int, tuple[Any, np.ndarray]] = {}
+            requests: list[Request] = []
+            for key, like in items.items():
+                if torch_interop.is_torch_tensor(like):
+                    view = torch_interop.to_numpy_view(like, allow_copy=False)
+                    torch_returns[len(plan)] = (like, view)
+                    like = view
+                if like is None:
                     requests.append(Request.meta_request(key))
+                    plan.append((key, requests[-1], None))
+                elif isinstance(like, Shard):
+                    data = like.data
+                    if torch_interop.is_torch_tensor(data):
+                        view = torch_interop.to_numpy_view(data, allow_copy=False)
+                        torch_returns[len(plan)] = (data, view)
+                        like = Shard(data=view, tensor_slice=like.tensor_slice)
+                    req = Request.from_tensor_slice(key, like.tensor_slice)
+                    req.tensor_val = like.data
+                    requests.append(req)
+                    plan.append((key, req, like))
+                elif isinstance(like, TensorSlice):
+                    requests.append(Request.from_tensor_slice(key, like))
                     plan.append((key, requests[-1], like))
-            elif isinstance(like, np.ndarray):
-                req = Request(key=key, tensor_val=like)
-                requests.append(req)
-                plan.append((key, req, like))
-            else:
-                raise TypeError(f"unsupported get target {type(like)} for {key!r}")
+                elif shd.is_jax_array(like) or shd.is_sharded_spec(like):
+                    # target_slices/build_array only need .shape/.sharding, so a
+                    # ShapeDtypeStruct works as a no-allocation restore target.
+                    targets = shd.target_slices(like)
+                    jax_targets[len(plan)] = targets
+                    sub_reqs = [Request.from_tensor_slice(key, ts) for _, ts in targets]
+                    requests.extend(sub_reqs)
+                    plan.append((key, sub_reqs, like))
+                elif shd.is_plain_spec(like):
+                    # Sharding-less ShapeDtypeStruct: fetch the whole tensor and
+                    # return a default-placed device array of the spec's dtype.
+                    # Warm path first: upload straight from the stamped segment.
+                    served = self._try_one_sided_device(key, like)
+                    if served is not None:
+                        pre_served[len(plan)] = served
+                        plan.append((key, None, like))
+                    else:
+                        requests.append(Request.meta_request(key))
+                        plan.append((key, requests[-1], like))
+                elif isinstance(like, np.ndarray):
+                    req = Request(key=key, tensor_val=like)
+                    requests.append(req)
+                    plan.append((key, req, like))
+                else:
+                    raise TypeError(f"unsupported get target {type(like)} for {key!r}")
 
-        # Batch-level plan seeding (the get_batch leg of the iteration-
-        # stable plan cache — previously only state-dict ops populated it):
-        # a repeated identical batch validates with ONE epoch check instead
-        # of per-key locates, and skips even that when every member has a
-        # one-sided plan (the stamped reads self-validate).
-        pc = self.plan_cache
-        batch_sig = self._batch_signature(items) if _seed_plan and pc else None
-        batch_plan = None
-        if batch_sig is not None and pc.peek("get_batch", "", batch_sig):
-            if not self._one_sided_covers(requests):
-                await self.placement_epoch()
-            batch_plan = pc.lookup("get_batch", "", batch_sig)
-            if batch_plan is not None:
-                if len(self._loc_cache) + len(batch_plan["located"]) > (
-                    self.LOC_CACHE_MAX
-                ):
-                    self._loc_cache.clear()
-                for k, infos in batch_plan["located"].items():
-                    self._loc_cache.setdefault(k, infos)
+            # Batch-level plan seeding (the get_batch leg of the iteration-
+            # stable plan cache — previously only state-dict ops populated it):
+            # a repeated identical batch validates with ONE epoch check instead
+            # of per-key locates, and skips even that when every member has a
+            # one-sided plan (the stamped reads self-validate).
+            pc = self.plan_cache
+            batch_sig = self._batch_signature(items) if _seed_plan and pc else None
+            batch_plan = None
+            if batch_sig is not None and pc.peek("get_batch", "", batch_sig):
+                if not self._one_sided_covers(requests):
+                    await self.placement_epoch()
+                batch_plan = pc.lookup("get_batch", "", batch_sig)
+                if batch_plan is not None:
+                    if len(self._loc_cache) + len(batch_plan["located"]) > (
+                        self.LOC_CACHE_MAX
+                    ):
+                        self._loc_cache.clear()
+                    for k, infos in batch_plan["located"].items():
+                        self._loc_cache.setdefault(k, infos)
         flat_results = await self._fetch(requests, prefer_volume=prefer_volume)
         if batch_sig is not None and batch_plan is None:
             pc.store(
@@ -1020,6 +1034,7 @@ class LocalClient:
         by_request = dict(zip((id(r) for r in requests), flat_results))
 
         out: dict[str, Any] = {}
+        h2d_s = 0.0  # the batch's "h2d" stage, booked once below
         for idx, (key, req_or_list, like) in enumerate(plan):
             if idx in pre_served:
                 out[key] = pre_served[idx]
@@ -1039,7 +1054,9 @@ class LocalClient:
                     if want_dtype is not None and arr.dtype != want_dtype:
                         arr = arr.astype(want_dtype)
                     parts.append((dev, arr))
+                t_h2d = time.perf_counter()
                 out[key] = shd.build_array(like, parts)
+                h2d_s += time.perf_counter() - t_h2d
             elif shd.is_plain_spec(like):
                 import jax.numpy as jnp
 
@@ -1049,7 +1066,9 @@ class LocalClient:
                         f"stored shape {tuple(arr.shape)} != spec shape "
                         f"{tuple(like.shape)} for key {key!r}"
                     )
-                out[key] = jnp.asarray(arr, dtype=like.dtype)
+                with span("h2d.dispatch", nbytes=arr.nbytes, parts=1) as sp:
+                    out[key] = jnp.asarray(arr, dtype=like.dtype)
+                h2d_s += sp.elapsed
             else:
                 out[key] = by_request[id(req_or_list)]
             if idx in torch_returns:
@@ -1060,6 +1079,8 @@ class LocalClient:
                 # never a silently unfilled tensor.
                 if out[key] is view:
                     out[key] = tensor
+        if h2d_s:
+            obs_timeline.observe_stage("get", "h2d", h2d_s)
         return out
 
     async def _get_batch_one_sided(self, items: dict) -> Optional[dict]:

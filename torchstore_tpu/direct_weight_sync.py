@@ -31,6 +31,7 @@ from torchstore_tpu import sharding as shd
 from torchstore_tpu.logging import LatencyTracker, get_logger
 from torchstore_tpu.native import copy_into
 from torchstore_tpu.observability import metrics as obs_metrics
+from torchstore_tpu.observability.tracing import span, trace_enabled
 from torchstore_tpu.state_dict_utils import flatten_state_dict
 from torchstore_tpu.transport import shared_memory as shm
 from torchstore_tpu.transport.types import TensorMeta, TensorSlice
@@ -241,6 +242,12 @@ class _PeerReadServer:
             self._server = None
 
 
+def _stage_copy(staged: np.ndarray, host_arr: np.ndarray) -> None:
+    """One host copy into a published staging buffer."""
+    with span("direct.stage_copy", nbytes=host_arr.nbytes):
+        copy_into(staged, host_arr)
+
+
 class DirectWeightSyncSource:
     """Registers a state dict's shards into pull-able staging buffers.
 
@@ -398,7 +405,7 @@ class DirectWeightSyncSource:
                     # seqlock that brackets these staging writes (readers
                     # validate against it) — not an unstamped read.
                     staged = seg.view(TensorMeta.of(host_arr))  # tslint: disable=one-sided-discipline
-                    copy_into(staged, host_arr)
+                    _stage_copy(staged, host_arr)
                     self.segments[buffer_id] = seg
                     self.server.buffers[buffer_id] = staged
                     shm_name = seg.name
@@ -571,7 +578,8 @@ class DirectWeightSyncSource:
         for idx, (flat_key, ts_slice, arr) in enumerate(
             self._current_device_parts()
         ):
-            host_arr = np.ascontiguousarray(np.asarray(arr))
+            with span("d2h.wait", nbytes=arr.nbytes):
+                host_arr = np.ascontiguousarray(np.asarray(arr))
             buffer_id = self._host_fallback_ids.get(idx)
             if buffer_id is None:
                 buffer_id = self._next_id
@@ -589,7 +597,7 @@ class DirectWeightSyncSource:
                 and staged.shape == host_arr.shape
                 and staged.dtype == host_arr.dtype
             ):
-                copy_into(staged, host_arr)
+                _stage_copy(staged, host_arr)
                 host_arr = staged
             else:
                 # np.asarray of a jax array is a READ-ONLY view of the
@@ -645,17 +653,19 @@ class DirectWeightSyncSource:
         dests always read the arrays ``update_sources`` last installed."""
         if not self._registered:
             raise RuntimeError("register() must run before refresh()")
-        if self.device_info is not None:
-            # Device staging snapshots per pull; publish = one stable bump
-            # (which also invalidates the host-fallback staging cache).
-            self._bump_gen(2)
-            return
-        self._set_busy(True)  # reported odd while buffers are overwritten
-        try:
-            await self._refresh_host()
-        finally:
-            self._bump_gen(2)
-            self._set_busy(False)
+        with span("direct.refresh", device=self.device_info is not None):
+            if self.device_info is not None:
+                # Device staging snapshots per pull; publish = one stable
+                # bump (which also invalidates the host-fallback staging
+                # cache).
+                self._bump_gen(2)
+                return
+            self._set_busy(True)  # reported odd while buffers are overwritten
+            try:
+                await self._refresh_host()
+            finally:
+                self._bump_gen(2)
+                self._set_busy(False)
 
     async def _refresh_host(self) -> None:
         for flat_key, value in self._sources.items():
@@ -690,7 +700,7 @@ class DirectWeightSyncSource:
                     # refresh copy vanishes, matching RDMA's register-once
                     # read-live semantics.
                     continue
-                copy_into(staged, np.ascontiguousarray(host_arr))
+                _stage_copy(staged, np.ascontiguousarray(host_arr))
 
     def staging_state_dict(self) -> Optional[Any]:
         """The registered staging buffers in the ORIGINAL state-dict
@@ -948,6 +958,12 @@ class DirectWeightSyncDest:
                 for h in handle_list
             }
         )
+        async def pull_once(attempt: int) -> Any:
+            with span("direct.pull", attempt=attempt):
+                return await self._pull_once(
+                    all_handles, dest_state_dict, key_order, on_layer
+                )
+
         gens0 = None
         for attempt in (0, 1):
             try:
@@ -955,12 +971,8 @@ class DirectWeightSyncDest:
             except KeyError:
                 # Pre-generation source (or server without the op): serve
                 # the pull unchecked rather than failing it.
-                return await self._pull_once(
-                    all_handles, dest_state_dict, key_order, on_layer
-                )
-            result = await self._pull_once(
-                all_handles, dest_state_dict, key_order, on_layer
-            )
+                return await pull_once(attempt)
+            result = await pull_once(attempt)
             gens1 = list(
                 await asyncio.gather(
                     *(self._read_gen(h, p) for h, p in endpoints)
@@ -1210,23 +1222,31 @@ class DirectWeightSyncDest:
                     )
                     if hkey not in shard_raws and hkey not in need:
                         need.append(hkey)
-                reads = await asyncio.gather(
-                    *(
-                        self._read_shard(by_handle[hk][0], row_ranges[hk])
-                        for hk in need
+                with span("direct.read", shards=len(need)):
+                    reads = await asyncio.gather(
+                        *(
+                            self._read_shard(by_handle[hk][0], row_ranges[hk])
+                            for hk in need
+                        )
                     )
-                )
                 for hk, read in zip(need, reads):
                     shard_raws[hk] = read
                     ops_bytes += read[0].nbytes
-                for op in ops_by_key[flat_key]:
-                    hkey = (
-                        op.handle.hostname,
-                        op.handle.port,
-                        op.handle.buffer_id,
-                    )
-                    arr, row0 = shard_raws[hkey]
-                    self._apply_op(op, arr, row0, landings)
+                with span("direct.land", key=flat_key) as sp:
+                    if trace_enabled():
+                        sp.set(
+                            nbytes=sum(
+                                buf.nbytes for _, buf in landings[flat_key]
+                            )
+                        )
+                    for op in ops_by_key[flat_key]:
+                        hkey = (
+                            op.handle.hostname,
+                            op.handle.port,
+                            op.handle.buffer_id,
+                        )
+                        arr, row0 = shard_raws[hkey]
+                        self._apply_op(op, arr, row0, landings)
                 parts = landings[flat_key]
                 if flat_key in inplace_targets:
                     out_flat[flat_key] = parts[0][1]
@@ -1241,18 +1261,21 @@ class DirectWeightSyncDest:
             tracker.track_step("reads", ops_bytes)
             tracker.track_step("rebuild")
         else:
-            reads = await asyncio.gather(
-                *(
-                    self._read_shard(handle, row_ranges[hkey])
-                    for hkey, (handle, _) in by_handle.items()
+            with span("direct.read", shards=len(by_handle)):
+                reads = await asyncio.gather(
+                    *(
+                        self._read_shard(handle, row_ranges[hkey])
+                        for hkey, (handle, _) in by_handle.items()
+                    )
                 )
-            )
             shard_raws = dict(zip(by_handle.keys(), reads))
             ops_bytes = 0
-            for hkey, (arr, row0) in shard_raws.items():
-                ops_bytes += arr.nbytes
-                for op in by_handle[hkey][1]:
-                    self._apply_op(op, arr, row0, landings)
+            with span("direct.land") as sp:
+                for hkey, (arr, row0) in shard_raws.items():
+                    ops_bytes += arr.nbytes
+                    for op in by_handle[hkey][1]:
+                        self._apply_op(op, arr, row0, landings)
+                sp.set(nbytes=ops_bytes)
             tracker.track_step("reads", ops_bytes)
 
             for flat_key, parts in landings.items():
@@ -1713,7 +1736,8 @@ def _land_device(target, arr):
             arr = arr.astype(want_dtype)
         sharding = getattr(target, "sharding", None)
         if sharding is not None and sharding != arr.sharding:
-            arr = jax.device_put(arr, sharding)
+            with span("h2d.dispatch", nbytes=arr.nbytes, parts=1):
+                arr = jax.device_put(arr, sharding)
         return arr
     if isinstance(target, _Shard):
         region = tuple(
@@ -1768,7 +1792,8 @@ def _rebuild(target, parts: list[tuple[TensorSlice, np.ndarray]]):
         import jax.numpy as jnp
 
         ((_, arr),) = parts
-        return jnp.asarray(arr, dtype=target.dtype)
+        with span("h2d.dispatch", nbytes=arr.nbytes, parts=1):
+            return jnp.asarray(arr, dtype=target.dtype)
     # numpy target: single full slice, filled in place.
     ((_, arr),) = parts
     copy_into(target, arr)

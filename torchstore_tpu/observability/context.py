@@ -17,8 +17,10 @@ This module carries a W3C-traceparent-shaped context (``trace_id`` +
   into one Perfetto timeline where the shared trace id (and parent links)
   align client, controller, and volume tracks.
 
-Ids are hex strings (16 hex chars — 8 random bytes), cheap to mint per
-logical op. Context creation is O(two contextvar sets); when tracing is
+Ids are hex strings (16 hex chars — 64 random bits from a per-process
+generator seeded by the OS, reseeded in a forked child), cheap to mint per
+logical op and per span: no system call, which on a sandboxed host costs
+more than the rest of a span (6 of 11 us on the chip's host; PERF.md, PR 23). Context creation is O(two contextvar sets); when tracing is
 disabled only the ids ride the RPC frames (useful for slow-op log
 correlation) and nothing is buffered.
 """
@@ -26,7 +28,8 @@ correlation) and nothing is buffered.
 from __future__ import annotations
 
 import contextvars
-import secrets
+import os
+import random
 from typing import Optional
 
 _trace_id: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
@@ -37,8 +40,14 @@ _parent_span_id: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
 )
 
 
+# Ids name spans; they guard nothing, so a PRNG serves. Its own instance: a
+# caller's ``random.seed(0)`` must not make two processes mint the same ids.
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def new_id() -> str:
-    return secrets.token_hex(8)
+    return f"{_ids.getrandbits(64):016x}"
 
 
 def trace_id() -> Optional[str]:

@@ -82,6 +82,9 @@ SLO_OVERLAP_MIN = "TORCHSTORE_TPU_SLO_OVERLAP_MIN"
 #
 #   plan            metadata resolve: locate (RPC or stamped), plan/epoch
 #                   validation, request building, placement selection
+#   d2h             device->host: issuing the copies of a put batch's device
+#                   arrays and waiting for their bytes
+#   h2d             host->device: dispatching fetched bytes to the device
 #   transport       the wire leg: handshake + frames + RPC data movement
 #   landing         landing copies: bytes into store/destination memory
 #   stamp_verify    one-sided seqlock checks (pre-copy match + post-copy
@@ -92,6 +95,8 @@ SLO_OVERLAP_MIN = "TORCHSTORE_TPU_SLO_OVERLAP_MIN"
 STAGE_CATALOG = frozenset(
     {
         "plan",
+        "d2h",
+        "h2d",
         "transport",
         "landing",
         "stamp_verify",
@@ -117,6 +122,12 @@ _STAGE_P50 = obs_metrics.gauge(
 _STAGE_P99 = obs_metrics.gauge(
     "ts_op_stage_p99_seconds",
     "Rolling-window p99 stage wall time, by op and stage",
+)
+# The stage totals above decay (60 s half-life) and the digests are rolling:
+# neither can be read as a difference over a window. This one never decays.
+_STAGE_SECONDS = obs_metrics.histogram(
+    "ts_op_stage_seconds",
+    "Cumulative stage wall time (sum and count never decay), by op and stage",
 )
 
 
@@ -378,8 +389,11 @@ def observe_stage(op: str, stage: str, dur_s: float) -> None:
     """Record one wall-clock stage segment of a logical op. ``stage`` MUST
     name a :data:`STAGE_CATALOG` entry (raises ValueError otherwise — the
     ``stage-discipline`` tslint rule catches drift statically; this is the
-    loud runtime backstop)."""
+    loud runtime backstop). Also feeds the cumulative
+    ``ts_op_stage_seconds{op,stage}`` histogram, whose sum and count can be
+    read as a difference over a window."""
     _stages.observe(op, stage, dur_s)
+    _STAGE_SECONDS.observe(dur_s, op=op, stage=stage)
 
 
 def dominant_stage(op: str) -> Optional[str]:

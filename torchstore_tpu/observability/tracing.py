@@ -20,6 +20,13 @@ wall time):
 Cost when disabled (no env var): one ``perf_counter`` call per span and an
 attribute check — nothing is buffered.
 
+One clock with the device trace: while tracing is enabled, a span opened in a
+process that has ALREADY imported jax is also a
+``jax.profiler.TraceAnnotation("ts/<name>")``, so under a running profiler
+session it sits on the profiler's ``/host:`` plane beside ``XLA Ops``. This
+module never imports jax itself: a chip belongs to one process, and the
+volume/controller actors must stay jax-free — they simply get no annotation.
+
 Events stream to disk in the JSON *array* format, appending every
 ``FLUSH_EVERY`` events — the format's closing ``]`` is optional, so the file
 is loadable after a crash and memory stays bounded in long-running loops.
@@ -34,6 +41,7 @@ import glob as _glob
 import json
 import os
 import re
+import sys
 import threading
 import time
 from typing import Optional
@@ -48,6 +56,8 @@ ENV_TRACE = "TORCHSTORE_TPU_TRACE"
 # events must survive into the merge) from "leftover file of a FINISHED
 # run" (must be cleared, or a reused output directory merges dead spans).
 ENV_TRACE_RUN = "TORCHSTORE_TPU_TRACE_RUN"
+# Store spans on the profiler's host plane carry this prefix.
+ANNOTATION_PREFIX = "ts/"
 
 
 def _current_run_id() -> str:
@@ -85,7 +95,10 @@ class TraceCollector:
 
     def __init__(self) -> None:
         self.path = os.environ.get(ENV_TRACE)
-        self.events: list[dict] = []
+        # Buffered events stay plain tuples (name, start_s, dur_s, args,
+        # tid) until a flush turns them into Chrome-trace objects: a span's
+        # exit pays one append, and the chunk is encoded in one go.
+        self.events: list[tuple] = []
         self._lock = threading.Lock()
         self._registered = False
         self._resolved_path: Optional[str] = None
@@ -107,23 +120,7 @@ class TraceCollector:
         ``args`` pane; a ``bytes`` entry gets a derived GBps alongside."""
         if not self.path:
             return
-        event = {
-            "name": name,
-            "cat": "torchstore",
-            "ph": "X",
-            "ts": start_s * 1e6,
-            "dur": dur_s * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFF,
-        }
-        if args:
-            args = dict(args)
-            nbytes = args.get("bytes")
-            if isinstance(nbytes, (int, float)) and "GBps" not in args:
-                args["GBps"] = (
-                    round(nbytes / dur_s / 1e9, 3) if dur_s > 0 else None
-                )
-            event["args"] = args
+        event = (name, start_s, dur_s, args, threading.get_ident() & 0xFFFF)
         with self._lock:
             self.events.append(event)
             if not self._registered:
@@ -231,10 +228,33 @@ class TraceCollector:
             pass
         return pid_path
 
+    @staticmethod
+    def _chrome_event(event: tuple, pid: int) -> dict:
+        name, start_s, dur_s, args, tid = event
+        out = {
+            "name": name,
+            "cat": "torchstore",
+            "ph": "X",
+            "ts": start_s * 1e6,
+            "dur": dur_s * 1e6,
+            "pid": pid,
+            "tid": tid,
+        }
+        if args:
+            args = dict(args)
+            nbytes = args.get("bytes")
+            if isinstance(nbytes, (int, float)) and "GBps" not in args:
+                args["GBps"] = (
+                    round(nbytes / dur_s / 1e9, 3) if dur_s > 0 else None
+                )
+            out["args"] = args
+        return out
+
     def _flush_locked(self) -> None:
         if not self.path or not self.events:
             return
-        chunk = self.events
+        pid = os.getpid()
+        chunk = [self._chrome_event(event, pid) for event in self.events]
         self.events = []
         try:
             path = self._resolve_path()
@@ -248,15 +268,17 @@ class TraceCollector:
                     {
                         "name": "process_name",
                         "ph": "M",
-                        "pid": os.getpid(),
+                        "pid": pid,
                         "args": {"name": process_label()},
                     },
                 )
+            # ONE json.dumps for the chunk (the one-shot C encoder; a call
+            # per event costs three times the encoding), minus the list's
+            # own brackets; one write.
+            text = json.dumps(chunk)[1:-1]
             with open(path, "a") as f:
-                for event in chunk:
-                    f.write("[\n" if not self._wrote_header else ",\n")
-                    self._wrote_header = True
-                    json.dump(event, f)
+                f.write(("[\n" if not self._wrote_header else ",\n") + text)
+            self._wrote_header = True
         except OSError:
             pass
 
@@ -307,7 +329,7 @@ class span:
     so per-process files merge into one cross-process tree.
     """
 
-    __slots__ = ("name", "attrs", "_t0", "_span_id", "_token")
+    __slots__ = ("name", "attrs", "_t0", "_span_id", "_token", "_annotation")
 
     def __init__(self, name: str, **attrs) -> None:
         self.name = name
@@ -315,19 +337,37 @@ class span:
         self._t0 = 0.0
         self._span_id = None
         self._token = None
+        self._annotation = None
 
     def set(self, **attrs) -> "span":
         self.attrs.update(attrs)
         return self
+
+    @property
+    def elapsed(self) -> float:
+        """Seconds since the span was entered: read right after its block,
+        the block's duration (enabled or not)."""
+        return time.perf_counter() - self._t0
 
     def __enter__(self) -> "span":
         self._t0 = time.perf_counter()
         if _collector.enabled:
             self._span_id = trace_context.new_id()
             self._token = trace_context.push_span(self._span_id)
+            profiler = sys.modules.get("jax.profiler")
+            if profiler is not None:
+                # A no-op without a profiler session; with one, the span is
+                # an event on the device trace's clock.
+                self._annotation = profiler.TraceAnnotation(
+                    ANNOTATION_PREFIX + self.name
+                )
+                self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         parent = None
         if self._token is not None:
             parent = trace_context.token_parent(self._token)

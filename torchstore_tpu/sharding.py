@@ -11,10 +11,11 @@ never pay for it.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import numpy as np
 
+from torchstore_tpu.observability.tracing import span, trace_enabled
 from torchstore_tpu.transport.types import Request, TensorSlice
 from torchstore_tpu.utils import Box
 
@@ -74,7 +75,39 @@ def _is_demotable(sharding) -> bool:
     return sharding.is_fully_replicated
 
 
-def put_requests(key: str, x) -> list[Request]:
+class D2HSeconds:
+    """Seconds one put batch spent issuing device->host copies and waiting
+    for their bytes: the put's ``d2h`` stage (observability/timeline.py)."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+
+
+def issue_d2h(arrays, d2h: Optional[D2HSeconds] = None) -> None:
+    """Start the device->host copy of every array in ``arrays`` (whole
+    arrays or shards' ``.data``) before any is awaited: one ``d2h.issue``
+    span for the lot."""
+    with span("d2h.issue") as sp:
+        for arr in arrays:
+            arr.copy_to_host_async()
+    if d2h is not None:
+        d2h.seconds += sp.elapsed
+
+
+def _to_host(arr, d2h: Optional[D2HSeconds]) -> np.ndarray:
+    """The wait for one device array's bytes (``d2h.wait``)."""
+    with span("d2h.wait", nbytes=arr.nbytes) as sp:
+        out = np.asarray(arr)
+    if d2h is not None:
+        d2h.seconds += sp.elapsed
+    return out
+
+
+def put_requests(
+    key: str, x, d2h: Optional[D2HSeconds] = None
+) -> list[Request]:
     """Expand a jax.Array into per-addressable-shard put requests.
 
     One process may own several devices (a TPU host owns 4-8 chips), so a
@@ -83,23 +116,23 @@ def put_requests(key: str, x) -> list[Request]:
     OVERLAPPED: every shard's async D2H copy is issued before the first is
     awaited, so transfers from different chips ride their DMA engines
     concurrently (the reference overlaps CUDA side-stream copies the same
-    way, /root/reference/torchstore/transport/shared_memory.py:362-420)."""
+    way, /root/reference/torchstore/transport/shared_memory.py:362-420).
+    ``d2h`` accumulates the seconds spent issuing and waiting."""
     import jax  # noqa: F401
 
     sharding = x.sharding
     if _is_demotable(sharding):
-        x.copy_to_host_async()
-        return [Request.from_tensor(key, np.asarray(x))]
+        issue_d2h((x,), d2h)
+        return [Request.from_tensor(key, _to_host(x, d2h))]
     mesh = sharding.mesh
     mesh_shape = tuple(int(s) for s in mesh.devices.shape)
     coords_map = _mesh_coords_map(mesh)
     global_shape = tuple(int(s) for s in x.shape)
     shards = list(x.addressable_shards)
-    for shard in shards:
-        shard.data.copy_to_host_async()
+    issue_d2h((shard.data for shard in shards), d2h)
     requests = []
     for shard in shards:
-        data = np.asarray(shard.data)
+        data = _to_host(shard.data, d2h)
         offsets = tuple(int(sl.start or 0) for sl in shard.index)
         ts = TensorSlice(
             offsets=offsets,
@@ -159,16 +192,21 @@ def build_array(like, parts: list[tuple[Any, np.ndarray]]):
     import jax
 
     sharding = like.sharding
-    if _is_demotable(sharding):
-        # target_slices produced a single full-array part; replicate it onto
-        # every addressable device of the target sharding.
-        ((_, arr),) = parts
-        arrays = [jax.device_put(arr, d) for d in sharding.addressable_devices]
-    else:
-        arrays = [jax.device_put(arr, dev) for dev, arr in parts]
-    return jax.make_array_from_single_device_arrays(
-        tuple(int(s) for s in like.shape), sharding, arrays
-    )
+    with span("h2d.dispatch", parts=len(parts)) as sp:
+        if trace_enabled():
+            sp.set(nbytes=sum(arr.nbytes for _, arr in parts))
+        if _is_demotable(sharding):
+            # target_slices produced a single full-array part; replicate it
+            # onto every addressable device of the target sharding.
+            ((_, arr),) = parts
+            arrays = [
+                jax.device_put(arr, d) for d in sharding.addressable_devices
+            ]
+        else:
+            arrays = [jax.device_put(arr, dev) for dev, arr in parts]
+        return jax.make_array_from_single_device_arrays(
+            tuple(int(s) for s in like.shape), sharding, arrays
+        )
 
 
 def full_box(global_shape: tuple[int, ...]) -> Box:

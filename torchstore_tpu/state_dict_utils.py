@@ -26,6 +26,7 @@ from torchstore_tpu import torch_interop
 from torchstore_tpu.logging import LatencyTracker, get_logger
 from torchstore_tpu.native import copy_into
 from torchstore_tpu.observability import metrics as obs_metrics
+from torchstore_tpu.observability.tracing import span
 from torchstore_tpu.transport.types import _np_dtype  # bf16-aware name->dtype
 
 logger = get_logger("torchstore_tpu.state_dict")
@@ -1019,9 +1020,10 @@ def _quant_result(st: dict, user_leaf: Any, dtype_name: Optional[str] = None):
 
         host = arr.astype(np.dtype(user_leaf.dtype))
         sharding = getattr(user_leaf, "sharding", None)
-        if sharding is not None:
-            return jax.device_put(host, sharding)
-        return jnp.asarray(host)
+        with span("h2d.dispatch", nbytes=host.nbytes, parts=1):
+            if sharding is not None:
+                return jax.device_put(host, sharding)
+            return jnp.asarray(host)
     return arr.astype(_np_dtype(want))
 
 
@@ -1183,9 +1185,10 @@ async def _put_state_dict_direct(
     source = cache.sources.get((key, rank))
     if source is None:
         source = DirectWeightSyncSource(config=getattr(client, "_config", None))
-        handles = await source.register(
-            state_dict, rank, transfer_dtype, num_ranks=num_ranks
-        )
+        with span("direct.register", key=key, rank=rank):
+            handles = await source.register(
+                state_dict, rank, transfer_dtype, num_ranks=num_ranks
+            )
         cache.sources[(key, rank)] = source
         published = {"handles": handles}
         if source.device_info is not None:

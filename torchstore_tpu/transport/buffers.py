@@ -162,13 +162,19 @@ class TransportBuffer(ABC):
                 nbytes=nbytes,
             ):
                 if self.requires_handshake and "put" in self.handshake_ops:
-                    await self._perform_handshake(volume, requests, op="put")
+                    # Self time (outside what _post_handshake spans) is the
+                    # handshake RPC as the client waits for it.
+                    with tracing.span("transport.handshake", keys=len(requests)):
+                        await self._perform_handshake(
+                            volume, requests, op="put"
+                        )
                 await self._pre_put_hook(volume, requests)
                 metas = [r.meta_only() for r in requests]
                 put = volume.actor.put
-                reply = await put.with_timeout(
-                    transfer_timeout(put._effective_timeout(), nbytes)
-                ).call_one(self, metas)
+                with tracing.span("transport.put_rpc", keys=len(requests)):
+                    reply = await put.with_timeout(
+                        transfer_timeout(put._effective_timeout(), nbytes)
+                    ).call_one(self, metas)
                 if isinstance(reply, dict) and "write_gens" in reply:
                     self.write_gens = reply["write_gens"]
                     reply = reply["reply"]

@@ -309,11 +309,12 @@ def upload_stamped(view, recheck, dtype=None, sharding=None):
         # first (the cost real accelerators pay in the H2D DMA anyway);
         # the recheck below still validates it was not torn.
         view = np.asarray(view).copy()
-    out = (
-        jax.device_put(view, sharding)
-        if sharding is not None
-        else jax.device_put(view)
-    )
+    with tracing.span("h2d.dispatch", nbytes=view.nbytes, parts=1):
+        out = (
+            jax.device_put(view, sharding)
+            if sharding is not None
+            else jax.device_put(view)
+        )
     if dtype is not None and str(out.dtype) != str(dtype):
         out = out.astype(dtype)  # on-device; depends on the H2D transfer
     if not finalize_stamped(out, recheck):

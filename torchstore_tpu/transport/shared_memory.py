@@ -75,6 +75,7 @@ from torchstore_tpu.observability import ledger as obs_ledger
 from torchstore_tpu.observability import metrics as obs_metrics
 from torchstore_tpu.observability import profile as obs_profile
 from torchstore_tpu.observability import timeline as obs_timeline
+from torchstore_tpu.observability import tracing
 from torchstore_tpu.utils import spawn_logged
 from torchstore_tpu.transport import landing
 from torchstore_tpu.transport.buffers import (
@@ -1765,60 +1766,87 @@ class SharedMemoryTransportBuffer(TransportBuffer):
         offered: dict[int, ShmDescriptor] = reply or {}
         arena = self.arena_plan
         arena_seg: Optional[ShmSegment] = None
-        if arena:
-            arena_seg = self._attach_arena(volume, cache, offered, requests)
         # Landing copies for the whole batch are collected first, then fanned
         # out to the shared overlap pool: copies run concurrently with each
         # other (and, chunked, within one huge tensor) while the event loop
         # stays free for sibling volumes' RPCs.
         pairs: list[tuple[np.ndarray, np.ndarray]] = []
-        for idx, req in enumerate(requests):
-            if req.is_object:
-                self.objects[idx] = req.objects
-                continue
-            arr = np.ascontiguousarray(req.tensor_val)
-            meta = req.meta_only().tensor_meta
-            if arena_seg is not None and idx in arena["offsets"]:
-                # Arena member: no per-key descriptor rides the RPC — the
-                # server rebuilds every member view from the (already
-                # carried) arena plan plus the request metas.
-                off = arena["offsets"][idx]
-                cache.key_to_segments.setdefault(req.key, set()).add(
-                    arena_seg.name
+        # Segment attach / create for the batch: a publish that gets no
+        # pooled offer goes cold here (ShmSegment.create per request).
+        offer_hit = cold_create = created_bytes = 0
+        with tracing.span("shm.attach", keys=len(requests)) as sp:
+            if arena:
+                arena_seg, created = self._attach_arena(
+                    volume, cache, offered, requests
                 )
-                if arr.nbytes:
-                    pairs.append((arena_seg.view(meta, off), arr))
-                self._client_segments[idx] = arena_seg
-                continue
-            desc = offered.get(idx)
-            if desc is not None and desc.meta == meta:
-                seg = cache.attach(desc, req.key, volume.volume_id)
-                _CLIENT_ATTACH.inc(outcome="offer_hit")
-            else:
-                _CLIENT_ATTACH.inc(outcome="cold_create")
-                seg = ShmSegment.create(max(arr.nbytes, 1))
-                desc = ShmDescriptor(seg.name, seg.size, meta)
-                cache.segments[seg.name] = seg
-                cache.key_to_segments.setdefault(req.key, set()).add(seg.name)
-                cache.seg_volume[seg.name] = volume.volume_id
-            # THE hot memcpy: client array -> shared segment (native
-            # multi-threaded path; overlapped below).
-            pairs.append((seg.view(meta, desc.offset), arr))
-            self.descriptors[idx] = desc
-            self._client_segments[idx] = seg
-        await landing.land_async(
-            pairs, stage="put", copy=fast_copy, config=self.config
-        )
+                if created:
+                    cold_create += 1
+                    created_bytes += arena_seg.size
+                else:
+                    offer_hit += 1
+            for idx, req in enumerate(requests):
+                if req.is_object:
+                    self.objects[idx] = req.objects
+                    continue
+                arr = np.ascontiguousarray(req.tensor_val)
+                meta = req.meta_only().tensor_meta
+                if arena_seg is not None and idx in arena["offsets"]:
+                    # Arena member: no per-key descriptor rides the RPC —
+                    # the server rebuilds every member view from the
+                    # (already carried) arena plan plus the request metas.
+                    off = arena["offsets"][idx]
+                    cache.key_to_segments.setdefault(req.key, set()).add(
+                        arena_seg.name
+                    )
+                    if arr.nbytes:
+                        pairs.append((arena_seg.view(meta, off), arr))
+                    self._client_segments[idx] = arena_seg
+                    continue
+                desc = offered.get(idx)
+                if desc is not None and desc.meta == meta:
+                    seg = cache.attach(desc, req.key, volume.volume_id)
+                    _CLIENT_ATTACH.inc(outcome="offer_hit")
+                    offer_hit += 1
+                else:
+                    _CLIENT_ATTACH.inc(outcome="cold_create")
+                    seg = ShmSegment.create(max(arr.nbytes, 1))
+                    cold_create += 1
+                    created_bytes += seg.size
+                    desc = ShmDescriptor(seg.name, seg.size, meta)
+                    cache.segments[seg.name] = seg
+                    cache.key_to_segments.setdefault(req.key, set()).add(
+                        seg.name
+                    )
+                    cache.seg_volume[seg.name] = volume.volume_id
+                pairs.append((seg.view(meta, desc.offset), arr))
+                self.descriptors[idx] = desc
+                self._client_segments[idx] = seg
+            sp.set(
+                offer_hit=offer_hit,
+                cold_create=cold_create,
+                created_bytes=created_bytes,
+            )
+        # THE hot memcpy: client arrays -> shared segments (native
+        # multi-threaded path, overlapped). First-touch page faults of a
+        # freshly created segment land here.
+        with tracing.span("shm.land", pairs=len(pairs)) as sp:
+            if tracing.trace_enabled():
+                sp.set(nbytes=sum(src.nbytes for _, src in pairs))
+            await landing.land_async(
+                pairs, stage="put", copy=fast_copy, config=self.config
+            )
 
     def _attach_arena(
         self, volume, cache: "ShmClientCache", offered: dict, requests
-    ) -> ShmSegment:
+    ) -> tuple[ShmSegment, bool]:
         """Resolve the batch's shared arena segment: the handshake's pooled
-        offer when one arrived, a cold create otherwise."""
+        offer when one arrived, a cold create otherwise. Returns the segment
+        and whether it was created here."""
         arena = self.arena_plan
         size = max(int(arena["total"]), 1)
         desc = offered.get(ARENA_OFFER_KEY)
-        if desc is not None and desc.segment_size >= size:
+        created = desc is None or desc.segment_size < size
+        if not created:
             first_key = requests[next(iter(arena["offsets"]))].key
             seg = cache.attach(desc, first_key, volume.volume_id)
             _CLIENT_ATTACH.inc(outcome="offer_hit")
@@ -1831,7 +1859,7 @@ class SharedMemoryTransportBuffer(TransportBuffer):
         arena["segment_size"] = seg.size
         landing.ARENA_KEYS.inc(len(arena["offsets"]), transport="shm")
         landing.ARENA_BYTES.inc(sum(arena["sizes"]), transport="shm")
-        return seg
+        return seg, created
 
     def _handle_put_reply(self, volume, reply, requests) -> None:
         cache: ShmClientCache = volume.transport_context.get_cache(ShmClientCache)

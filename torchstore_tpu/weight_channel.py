@@ -387,17 +387,20 @@ class WeightPublisher:
         always internally consistent (one step's weights, never a mix)."""
         from torchstore_tpu import state_dict_utils
 
-        client = self._resolve_client()
-        version = await self._resolve_next_version(client)
-        data_key = (
-            f"{self.name}/direct" if direct else _version_key(self.name, version)
-        )
-        if direct:
-            quant_mode, delta_ctx = None, None
-        else:
-            quant_mode, delta_ctx = self._delta_ctx_for(
-                client, version, transfer_quant, delta
+        with span("weight_channel.resolve_version", channel=self.name):
+            client = self._resolve_client()
+            version = await self._resolve_next_version(client)
+            data_key = (
+                f"{self.name}/direct"
+                if direct
+                else _version_key(self.name, version)
             )
+            if direct:
+                quant_mode, delta_ctx = None, None
+            else:
+                quant_mode, delta_ctx = self._delta_ctx_for(
+                    client, version, transfer_quant, delta
+                )
         with span(
             "weight_channel.publish",
             channel=self.name,
@@ -416,7 +419,8 @@ class WeightPublisher:
             # Pointer write LAST: subscribers woken by it see a committed dict.
             await self._commit(client, version)
         if not direct:
-            await self._gc(client, version)
+            with span("weight_channel.gc", channel=self.name, version=version):
+                await self._gc(client, version)
         return version
 
     async def _gc(self, client, version: int) -> None:
@@ -521,11 +525,12 @@ class ChannelStream:
 
         if self._stream is None:
             pub = self._pub
-            client = pub._resolve_client()
-            self.version = await pub._resolve_next_version(client)
-            quant_mode, delta_ctx = pub._delta_ctx_for(
-                client, self.version, self._transfer_quant, self._delta
-            )
+            with span("weight_channel.resolve_version", channel=pub.name):
+                client = pub._resolve_client()
+                self.version = await pub._resolve_next_version(client)
+                quant_mode, delta_ctx = pub._delta_ctx_for(
+                    client, self.version, self._transfer_quant, self._delta
+                )
             self._stream = stream_sync.stream_state_dict(
                 client,
                 _version_key(pub.name, self.version),
@@ -560,7 +565,8 @@ class ChannelStream:
             # Pointer write LAST: barrier subscribers woken by it always
             # see a committed (sealed) dict, exactly like publish().
             await pub._commit(client, version)
-        await pub._gc(client, version)
+        with span("weight_channel.gc", channel=pub.name, version=version):
+            await pub._gc(client, version)
         return version
 
 
