@@ -159,6 +159,37 @@ def test_smoke_train_step_fits_the_chip(v5e):
     assert step + weights < HBM_BYTES, (step, weights)
 
 
+@pytest.mark.parametrize(
+    "shape, dtype",
+    [
+        ((8, 4096, 14336), jnp.bfloat16),  # Mixtral's stacked expert leaf, 939 MB
+        ((32000, 4096), jnp.bfloat16),  # its embedding, 262 MB
+        ((4096, 4096), jnp.bfloat16),  # q_proj: the smallest leaf that chunks
+    ],
+    ids=["experts", "embedding", "q_proj"],
+)
+def test_d2h_slice_program_holds_one_block(v5e, shape, dtype):
+    """The program a chunked device->host copy runs (`sharding._slicer`) at
+    Mixtral's widths: compiles for the chip, gives one block of at most a
+    chunk, and needs no temporary of the leaf's size beside it (a publish
+    holds two copies of the weights already)."""
+    from torchstore_tpu import sharding as shd
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    nbytes = int(np.prod(shape)) * 2
+    assert nbytes >= shd.D2H_CHUNK_THRESHOLD
+    axis, rows = shd._block_plan(shape, 2, shd.D2H_CHUNK_BYTES)
+    assert shape[axis] % rows == 0, "these leaves cut into equal blocks: one program"
+    at = jax.ShapeDtypeStruct((axis + 1,), jnp.int32, sharding=one_chip)
+    compiled = shd._slicer().lower(x, at, axis=axis, rows=rows).compile()
+    mem = compiled.memory_analysis()
+    block = rows * int(np.prod(shape[axis + 1 :])) * 2
+    assert shd.D2H_CHUNK_BYTES // 2 < block <= shd.D2H_CHUNK_BYTES
+    assert mem.output_size_in_bytes <= 2 * shd.D2H_CHUNK_BYTES
+    assert mem.temp_size_in_bytes <= shd.D2H_CHUNK_BYTES, mem.temp_size_in_bytes
+
+
 def test_device_cast_surfaces_a_kernel_error(monkeypatch):
     """``device_cast`` used to try a Pallas kernel and swallow ANY exception
     from it; whatever its kernel raises now reaches the caller."""

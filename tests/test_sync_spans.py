@@ -162,6 +162,15 @@ async def test_direct_sync_emits_every_span(traced, monkeypatch):
     assert len(named(events, "direct.register")) == 1
     assert len(named(events, "direct.refresh")) == 1
     assert_inside(events, "direct.stage_copy", "direct.register", "direct.refresh")
+    # A traced run of the direct cell must print `direct_stage_copy_s`: the
+    # register AND the refresh each hold a staging copy that took time.
+    for outer in named(events, "direct.register") + named(events, "direct.refresh"):
+        assert any(
+            e["dur"] > 0
+            and outer["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"]
+            for e in named(events, "direct.stage_copy")
+        ), f"no direct.stage_copy inside {outer['name']}"
     assert_inside(events, "d2h.issue", "direct.register", "direct.refresh")
     assert_inside(events, "d2h.wait", "direct.register", "direct.refresh")
     assert_inside(events, "direct.read", "direct.pull")

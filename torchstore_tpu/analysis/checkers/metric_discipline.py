@@ -93,6 +93,9 @@ ALLOWED_LABEL_KEYS = {
     # (observability/detect.py default_detectors — a closed, code-reviewed
     # set; history-discipline pins each one to a registered instrument).
     "detector",
+    # Device->host copies of a put: "chunked" or "whole", decided by
+    # sharding.chunk_plan — a closed set of two.
+    "path",
 }
 
 

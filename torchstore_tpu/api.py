@@ -1602,8 +1602,12 @@ async def shutdown(store_name: str = DEFAULT_STORE) -> None:
     store; otherwise, in the initializing process this resets + stops the
     volume/controller actors, elsewhere it only drops local caches
     (/root/reference/torchstore/api.py:100-109)."""
+    from torchstore_tpu import sharding as shd
     from torchstore_tpu import spmd as spmd_mod
 
+    # Recycled device->host landing buffers are a cache of this process:
+    # nothing of a store that is going should keep host memory.
+    shd.host_pool().clear()
     if await spmd_mod.shutdown(store_name):
         return
     handle = _stores.pop(store_name, None)

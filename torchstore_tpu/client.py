@@ -600,9 +600,12 @@ class LocalClient:
         if self._volumes_stale:
             await self._refresh_health()
         tracker = LatencyTracker("put_batch")
-        # Issue every device->host copy for the WHOLE batch up front so
-        # transfers overlap across arrays too, not just across one array's
-        # shards (shd.put_requests overlaps within an array).
+        # Issue the device->host copy of every SMALL array of the whole
+        # batch up front so transfers overlap across arrays too, not just
+        # across one array's shards. issue_d2h skips an array that leaves
+        # in chunks (shd.chunk_plan): shd.put_requests runs its window when
+        # its turn comes, and a whole copy as well would move every byte
+        # twice.
         d2h = shd.D2HSeconds()
         on_device = [v for v in items.values() if shd.is_jax_array(v)]
         if on_device:

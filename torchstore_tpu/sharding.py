@@ -11,10 +11,17 @@ never pay for it.
 
 from __future__ import annotations
 
+import collections
+import functools
+import math
+import threading
+import weakref
 from typing import Any, Optional
 
 import numpy as np
 
+from torchstore_tpu.native import copy_into
+from torchstore_tpu.observability import metrics as obs_metrics
 from torchstore_tpu.observability.tracing import span, trace_enabled
 from torchstore_tpu.transport.types import Request, TensorSlice
 from torchstore_tpu.utils import Box
@@ -85,13 +92,233 @@ class D2HSeconds:
         self.seconds = 0.0
 
 
+# An array of this many bytes or more leaves the device in row blocks of at
+# most D2H_CHUNK_BYTES, D2H_WINDOW of them in flight per device, into a
+# recycled host buffer; a smaller one leaves whole. Measured on a TPU v5e
+# (PERF.md section 6, PR 25): an array of 32 MiB or more, awaited whole,
+# lands in memory the allocator has just mapped and is held to the rate at
+# which fresh pages fault in (0.7 GB/s); 8 MiB blocks into touched memory
+# leave at 5.8 GB/s. Constants, not settings: they follow the allocator's
+# 32 MiB mmap ceiling, not a deployment.
+D2H_CHUNK_BYTES = 8 << 20
+D2H_CHUNK_THRESHOLD = 3 * D2H_CHUNK_BYTES
+D2H_WINDOW = 4
+
+_D2H_BYTES = obs_metrics.counter(
+    "ts_d2h_bytes_total",
+    "Bytes copied device to host for puts, by path (chunked/whole)",
+)
+_D2H_CHUNKS = obs_metrics.counter(
+    "ts_d2h_chunks_total", "Row-block chunks of chunked device to host copies"
+)
+_POOL_HITS = obs_metrics.counter(
+    "ts_d2h_pool_hits_total",
+    "Chunked device to host copies that landed in a recycled host buffer",
+)
+_POOL_MISSES = obs_metrics.counter(
+    "ts_d2h_pool_misses_total",
+    "Chunked device to host copies that landed in a fresh (unfaulted) buffer",
+)
+_POOL_BYTES = obs_metrics.gauge(
+    "ts_d2h_pool_bytes", "Host bytes the device to host buffer pool holds free"
+)
+
+
+class HostBufferPool:
+    """Recycled host buffers for chunked device->host copies: their pages
+    are already faulted in, which a fresh allocation of this size never is.
+
+    ``take`` hands out a uint8 array over the smallest free buffer that
+    fits (a miss allocates). The buffer comes back only when the last
+    reference to that array, or to any view of it, has died: whoever still
+    holds a put request's tensor keeps its bytes, and no later put can
+    write into memory an earlier put's reader can see. The pool holds free
+    at most what was out at once, the oldest buffers going first."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._free: list[np.ndarray] = []  # oldest release first
+        # Buffers given back and not yet counted: a finalizer may run while
+        # this very thread holds the lock (the collector starts anywhere),
+        # so giving back only appends and settles if the lock is free.
+        self._returned: collections.deque = collections.deque()
+        self._out = 0
+        self._most_out = 0
+
+    def take(self, nbytes: int) -> np.ndarray:
+        with self._lock:
+            self._settle()
+            fits = [b for b in self._free if b.nbytes >= nbytes]
+            raw = min(fits, key=lambda b: b.nbytes) if fits else None
+            if raw is None:
+                _POOL_MISSES.inc()
+                raw = np.empty(nbytes, np.uint8)
+            else:
+                _POOL_HITS.inc()
+                self._free = [b for b in self._free if b is not raw]
+            self._out += raw.nbytes
+            self._most_out = max(self._most_out, self._out)
+            self._settle()
+        # A memoryview between the two keeps every view's ``.base`` on
+        # ``owner`` (numpy collapses chains of ndarray bases), so ``owner``
+        # dies exactly when the last view of these bytes does.
+        owner = np.frombuffer(memoryview(raw), np.uint8, count=nbytes)
+        weakref.finalize(owner, self._give_back, raw).atexit = False
+        return owner
+
+    def _give_back(self, raw: np.ndarray) -> None:
+        self._returned.append(raw)
+        if self._lock.acquire(blocking=False):
+            try:
+                self._settle()
+            finally:
+                self._lock.release()
+
+    def _settle(self) -> None:
+        while self._returned:
+            raw = self._returned.popleft()
+            self._out -= raw.nbytes
+            self._free.append(raw)
+        held = sum(b.nbytes for b in self._free)
+        while self._free and held + self._out > self._most_out:
+            held -= self._free.pop(0).nbytes
+        _POOL_BYTES.set(held)
+
+    def clear(self) -> None:
+        """Drop every free buffer; what is still out is dropped when it
+        comes back."""
+        with self._lock:
+            self._most_out = 0
+            self._settle()
+
+
+_host_pool = HostBufferPool()
+
+
+def host_pool() -> HostBufferPool:
+    """The process's pool (``ts.shutdown`` empties it)."""
+    return _host_pool
+
+
+def chunk_plan(arr) -> Optional[tuple[int, int]]:
+    """How a single-device array (a whole array or a shard's ``.data``)
+    leaves the device: None for whole, or ``(axis, rows)`` for blocks of
+    ``rows`` indices along ``axis``, one index at a time on the axes before
+    it and everything after it. Decided from the array's bytes and shape
+    alone: whole under D2H_CHUNK_THRESHOLD, and whole for sub-byte dtypes
+    (packed on the device, a byte an element on the host)."""
+    nbytes = arr.nbytes
+    if nbytes < D2H_CHUNK_THRESHOLD:
+        return None
+    from jax import dtypes
+
+    if dtypes.itemsize_bits(arr.dtype) < 8:
+        return None
+    return _block_plan(tuple(arr.shape), arr.dtype.itemsize, D2H_CHUNK_BYTES)
+
+
+@functools.lru_cache(maxsize=4096)
+def _block_plan(
+    shape: tuple[int, ...], itemsize: int, chunk_bytes: int
+) -> Optional[tuple[int, int]]:
+    below = itemsize  # bytes of one index along ``axis``
+    for axis in reversed(range(len(shape))):
+        if below * shape[axis] > chunk_bytes:
+            most = chunk_bytes // below
+            # Equal blocks where the axis divides (one program, no tail).
+            rows = next(
+                (r for r in range(most, most // 2, -1) if shape[axis] % r == 0),
+                most,
+            )
+            return axis, rows
+        below *= shape[axis]
+    return None
+
+
+@functools.cache
+def _slicer():
+    """The one device program a chunked copy runs: a block of ``rows``
+    along ``axis`` starting at the TRACED index ``at``, so a leaf compiles
+    once (and once more for a ragged tail), never per chunk."""
+    import jax
+    from jax import lax
+
+    def block(x, at, *, axis: int, rows: int):
+        starts = [at[i] for i in range(axis + 1)] + [0] * (x.ndim - axis - 1)
+        sizes = (1,) * axis + (rows,) + x.shape[axis + 1 :]
+        return lax.dynamic_slice(x, starts, sizes).reshape(sizes[axis:])
+
+    return jax.jit(block, static_argnames=("axis", "rows"))
+
+
+class _ChunkedCopy:
+    """One big single-device array on its way into a pooled host buffer,
+    block by block; ``_host_copies`` keeps its window."""
+
+    def __init__(self, arr, plan: tuple[int, int]) -> None:
+        self.arr = arr
+        self.axis, self.rows = plan
+        shape = tuple(int(s) for s in arr.shape)
+        self.chunks = math.prod(shape[: self.axis]) * -(
+            -shape[self.axis] // self.rows
+        )
+        self._dest = host_pool().take(arr.nbytes).view(arr.dtype).reshape(shape)
+        self._blocks = (
+            (lead, start, min(self.rows, shape[self.axis] - start))
+            for lead in np.ndindex(*shape[: self.axis])
+            for start in range(0, shape[self.axis], self.rows)
+        )
+        self.pending: collections.deque = collections.deque()
+
+    def issue(self, d2h: Optional[D2HSeconds]) -> None:
+        """Start the next block's copy, if one is left."""
+        block = next(self._blocks, None)
+        if block is None:
+            return
+        lead, start, rows = block
+        with span("d2h.issue") as sp:
+            chunk = _slicer()(
+                self.arr,
+                np.asarray((*lead, start), np.int32),
+                axis=self.axis,
+                rows=rows,
+            )
+            chunk.copy_to_host_async()
+        self.pending.append((lead, start, rows, chunk))
+        if d2h is not None:
+            d2h.seconds += sp.elapsed
+
+    def land(self, d2h: Optional[D2HSeconds]) -> None:
+        """Wait for the oldest block, copy it to its rows and drop it (the
+        runtime's few MB of host memory go back to the heap, pages mapped,
+        for the next block)."""
+        lead, start, rows, chunk = self.pending.popleft()
+        with span(
+            "d2h.wait", nbytes=chunk.nbytes, chunks=self.chunks, window=D2H_WINDOW
+        ) as sp:
+            copy_into(self._dest[lead][start : start + rows], np.asarray(chunk))
+            del chunk
+        if d2h is not None:
+            d2h.seconds += sp.elapsed
+
+    def result(self) -> np.ndarray:
+        """The host array, read-only as ``np.asarray`` of a device array is."""
+        _D2H_BYTES.inc(self._dest.nbytes, path="chunked")
+        _D2H_CHUNKS.inc(self.chunks)
+        out = self._dest.view()
+        out.flags.writeable = False
+        return out
+
+
 def issue_d2h(arrays, d2h: Optional[D2HSeconds] = None) -> None:
     """Start the device->host copy of every array in ``arrays`` (whole
-    arrays or shards' ``.data``) before any is awaited: one ``d2h.issue``
-    span for the lot."""
+    arrays or shards' ``.data``) that leaves the device whole, before any
+    is awaited: one ``d2h.issue`` span for the lot. An array that leaves in
+    chunks is skipped: issued whole as well, every byte would move twice."""
     with span("d2h.issue") as sp:
         for arr in arrays:
-            arr.copy_to_host_async()
+            if chunk_plan(arr) is None:
+                arr.copy_to_host_async()
     if d2h is not None:
         d2h.seconds += sp.elapsed
 
@@ -100,9 +327,37 @@ def _to_host(arr, d2h: Optional[D2HSeconds]) -> np.ndarray:
     """The wait for one device array's bytes (``d2h.wait``)."""
     with span("d2h.wait", nbytes=arr.nbytes) as sp:
         out = np.asarray(arr)
+    _D2H_BYTES.inc(out.nbytes, path="whole")
     if d2h is not None:
         d2h.seconds += sp.elapsed
     return out
+
+
+def _host_copies(arrays: list, d2h: Optional[D2HSeconds]) -> list[np.ndarray]:
+    """Host copies of single-device arrays, in order. The small ones as
+    ever: every copy issued before the first is awaited. The big ones in
+    chunks, each device's window filled in turn, so copies from different
+    chips still ride their DMA engines at the same time."""
+    issue_d2h(arrays, d2h)
+    copies = {
+        i: _ChunkedCopy(arr, plan)
+        for i, arr in enumerate(arrays)
+        if (plan := chunk_plan(arr)) is not None
+    }
+    for copy in copies.values():
+        for _ in range(D2H_WINDOW):  # the window: one more only as one lands
+            copy.issue(d2h)
+    live = collections.deque(copies.values())
+    while live:
+        copy = live.popleft()
+        copy.land(d2h)
+        copy.issue(d2h)
+        if copy.pending:
+            live.append(copy)
+    return [
+        copies[i].result() if i in copies else _to_host(arr, d2h)
+        for i, arr in enumerate(arrays)
+    ]
 
 
 def put_requests(
@@ -113,26 +368,31 @@ def put_requests(
     One process may own several devices (a TPU host owns 4-8 chips), so a
     single put covers all addressable shards — the multi-controller analog of
     the reference's one-shard-per-rank DTensor put. Device->host staging is
-    OVERLAPPED: every shard's async D2H copy is issued before the first is
-    awaited, so transfers from different chips ride their DMA engines
-    concurrently (the reference overlaps CUDA side-stream copies the same
-    way, /root/reference/torchstore/transport/shared_memory.py:362-420).
-    ``d2h`` accumulates the seconds spent issuing and waiting."""
+    OVERLAPPED across chips (the reference overlaps CUDA side-stream copies
+    the same way, /root/reference/torchstore/transport/shared_memory.py:362-420):
+    shards under D2H_CHUNK_THRESHOLD have their async copies issued before
+    the first is awaited; bigger ones leave in row blocks, a window of them
+    in flight per chip (``_host_copies``). Either way a request holds ONE
+    contiguous host array per shard. ``d2h`` accumulates the seconds spent
+    issuing and waiting."""
     import jax  # noqa: F401
 
     sharding = x.sharding
     if _is_demotable(sharding):
-        issue_d2h((x,), d2h)
-        return [Request.from_tensor(key, _to_host(x, d2h))]
+        src = x
+        if chunk_plan(x) is not None and len(sharding.device_set) > 1:
+            src = x.addressable_data(0)  # replicated: one chip's copy
+        (data,) = _host_copies([src], d2h)
+        return [Request.from_tensor(key, data)]
     mesh = sharding.mesh
     mesh_shape = tuple(int(s) for s in mesh.devices.shape)
     coords_map = _mesh_coords_map(mesh)
     global_shape = tuple(int(s) for s in x.shape)
     shards = list(x.addressable_shards)
-    issue_d2h((shard.data for shard in shards), d2h)
     requests = []
-    for shard in shards:
-        data = _to_host(shard.data, d2h)
+    for shard, data in zip(
+        shards, _host_copies([shard.data for shard in shards], d2h)
+    ):
         offsets = tuple(int(sl.start or 0) for sl in shard.index)
         ts = TensorSlice(
             offsets=offsets,
